@@ -46,9 +46,9 @@ def pallas_calls(jaxpr) -> List:
 
 
 def kernel_src(eqn) -> str:
-    """The kernel's ``name_and_src_info`` string, e.g.
+    """The kernel body's source info, e.g.
     ``'_mt_jvps_kernel at .../kernels/lora_dual/kernel.py:161'``."""
-    return str(eqn.params.get("name_and_src_info"))
+    return str(eqn.params["jaxpr"].debug_info.func_src_info)
 
 
 def kernel_name(eqn) -> str:

@@ -38,7 +38,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -163,7 +162,7 @@ class ShardedExecutor:
                               row, cb, kp, self.microbatch, collect)
 
         payload_spec = P(self.axis) if collect else P()
-        out = shard_map(
+        out = jax.shard_map(
             (lambda b, p, rk, sid, row, cb, kp:
              ((lambda pl, aux:
                (pl if collect else jax.lax.psum(pl, self.axis), aux))
@@ -172,7 +171,7 @@ class ShardedExecutor:
             in_specs=(P(), P(), P(), P(self.axis), P(self.axis),
                       P(self.axis), P(self.axis)),
             out_specs=(payload_spec, P(self.axis)),
-            check_rep=False,
+            check_vma=False,
         )(base, peft, round_key, seed_ids, mask_rows, batches, keep)
         return out
 

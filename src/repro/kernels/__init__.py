@@ -22,5 +22,37 @@ written to HBM — see dispatch.py "Cotangent-known route".
 
 Each kernel ships ops.py (jit'd dispatch wrapper) and ref.py (pure-jnp
 oracle). Tests sweep shapes/dtypes in interpret mode (CPU) and assert
-allclose against the oracle; real-TPU deployment flips interpret=False.
+allclose against the oracle. Every kernel entry point takes ``interpret``
+as a required keyword: the dispatch layer passes ``interpret=False`` on the
+'pallas' backend (compiled Mosaic kernels on a TPU) and ``True`` on
+'interpret'; ``tests/test_tpu_compile.py`` compiles each entry point for a
+described v5e chip.
 """
+import jax
+import jax.numpy as jnp
+
+
+def mxu_dot(a, b):
+    """``jnp.dot`` with an f32 result, for kernel bodies.
+
+    Operands of mixed dtype are promoted first, as the jnp mirrors promote
+    them (``x.astype(a.dtype) @ a``). A bf16 x bf16 product is exact in the
+    f32 accumulator, so such a dot states DEFAULT precision: Mosaic takes
+    the fp32 contract precision that ``jax_default_matmul_precision=
+    "highest"`` asks for only on f32 operands, and refuses to compile it on
+    bf16 ones. f32 dots follow the configured precision."""
+    dt = jnp.promote_types(a.dtype, b.dtype)
+    precision = jax.lax.Precision.DEFAULT if dt == jnp.bfloat16 else None
+    return jnp.dot(a.astype(dt), b.astype(dt), precision=precision,
+                   preferred_element_type=jnp.float32)
+
+
+def sum_partials(parts):
+    """(..., T, 1) per-block jvp partials of a ``*_mt_jvps`` kernel -> (T,).
+
+    One row reduction per tangent: each lane sums its partials in the same
+    order whatever T is, so stacked tangents stay bitwise-equal to
+    single-tangent passes (a reduction over the leading axes of the whole
+    block lets XLA pick a T-dependent order)."""
+    T = parts.shape[-2]
+    return parts.reshape(-1, T).T.sum(axis=1)

@@ -39,9 +39,9 @@ tangent calls are wrapped in ``jax.custom_batching.custom_vmap`` so that
 vmap-of-tangents lowers DIRECTLY to the T=K multi-tangent kernel — one
 fused pallas_call per projection/mixer — instead of the Pallas default
 batching rule (which would re-grid the T=1 kernel over K and recompute the
-primal per tangent). Unexpected batching patterns (e.g. a batched primal)
-fall back to a sequential ``lax.map`` of the T=1 kernel, which is always
-correct.
+primal per tangent). A batched primal (the round's vmap over clients) vmaps
+the T=1 kernel instead: Pallas's own batching rule then adds a parallel grid
+axis over the lanes.
 
 Cotangent-known route (contraction epilogues)
 ---------------------------------------------
@@ -150,13 +150,13 @@ def _materialize(t, like):
     return t
 
 
-def _map_fallback(axis_size, in_batched, args, f):
-    """custom_vmap fallback for unexpected batching patterns: broadcast the
-    unbatched operands and run the T=1 tangent kernel sequentially."""
-    args_b = tuple(
-        a if b else jnp.broadcast_to(a, (axis_size,) + jnp.shape(a))
-        for a, b in zip(args, in_batched))
-    return jax.lax.map(lambda xs: f(*xs), args_b), True
+def _vmap_fallback(axis_size, in_batched, args, f):
+    """custom_vmap rule for batched primals — the round's vmap over clients,
+    where every lane has its own activations: each lane is an independent
+    problem, so the T=1 kernel is vmapped and Pallas's batching rule adds a
+    parallel grid axis (one kernel call for all lanes)."""
+    in_axes = tuple(0 if b else None for b in in_batched)
+    return jax.vmap(f, in_axes=in_axes, axis_size=axis_size)(*args), True
 
 
 def _stack_tangents(axis_size, tangents, batched):
@@ -195,8 +195,8 @@ def _lora_tangent_fn(scale: float, has_xd: bool, interpret: bool):
                 return lora_dual_mt_tangents(
                     x, xd, w, a, ad, b, bd, scale=scale,
                     interpret=interpret), True
-            return _map_fallback(axis_size, in_batched,
-                                 (x, w, a, b, xd, ad, bd), base)
+            return _vmap_fallback(axis_size, in_batched,
+                                  (x, w, a, b, xd, ad, bd), base)
     else:
         def base(x, w, a, b, ad, bd):
             return lora_dual_mt_tangents(
@@ -213,8 +213,8 @@ def _lora_tangent_fn(scale: float, has_xd: bool, interpret: bool):
                 return lora_dual_mt_tangents(
                     x, None, w, a, ad, b, bd, scale=scale,
                     interpret=interpret), True
-            return _map_fallback(axis_size, in_batched,
-                                 (x, w, a, b, ad, bd), base)
+            return _vmap_fallback(axis_size, in_batched,
+                                  (x, w, a, b, ad, bd), base)
     return f
 
 
@@ -239,8 +239,8 @@ def _wkv6_tangent_fn(has_ud: bool, interpret: bool):
                 return wkv6_scan_mt_tangents(
                     r, k, v, w, u, rd, kd, vd, wd, ud,
                     interpret=interpret), True
-            return _map_fallback(axis_size, in_batched,
-                                 (r, k, v, w, u, rd, kd, vd, wd, ud), base)
+            return _vmap_fallback(axis_size, in_batched,
+                                  (r, k, v, w, u, rd, kd, vd, wd, ud), base)
     else:
         def base(r, k, v, w, u, rd, kd, vd, wd):
             return wkv6_scan_mt_tangents(
@@ -257,8 +257,8 @@ def _wkv6_tangent_fn(has_ud: bool, interpret: bool):
                                                  (rd, kd, vd, wd), tb)
                 return wkv6_scan_mt_tangents(
                     r, k, v, w, u, rd, kd, vd, wd, interpret=interpret), True
-            return _map_fallback(axis_size, in_batched,
-                                 (r, k, v, w, u, rd, kd, vd, wd), base)
+            return _vmap_fallback(axis_size, in_batched,
+                                  (r, k, v, w, u, rd, kd, vd, wd), base)
     return f
 
 
@@ -281,8 +281,8 @@ def _swa_tangent_fn(window, interpret: bool):
             qd, kd, vd = _stack_tangents(axis_size, (qd, kd, vd), tb)
             return swa_attention_mt_tangents(
                 q, k, v, qd, kd, vd, window=window, interpret=interpret), True
-        return _map_fallback(axis_size, in_batched, (q, k, v, qd, kd, vd),
-                             base)
+        return _vmap_fallback(axis_size, in_batched, (q, k, v, qd, kd, vd),
+                              base)
     return f
 
 
@@ -305,8 +305,8 @@ def _mamba2_tangent_fn(interpret: bool):
             xd, bd, cd, dd = _stack_tangents(axis_size, (xd, bd, cd, dd), tb)
             return mamba2_ops.mamba2_scan_mt_tangents(
                 xdt, bm, cm, dec, xd, bd, cd, dd, interpret=interpret), True
-        return _map_fallback(axis_size, in_batched,
-                             (xdt, bm, cm, dec, xd, bd, cd, dd), base)
+        return _vmap_fallback(axis_size, in_batched,
+                              (xdt, bm, cm, dec, xd, bd, cd, dd), base)
     return f
 
 
@@ -422,8 +422,8 @@ def _lora_multi_fn(scale: float, backend: str):
                            a_sel)
             lo = jnp.einsum("b...r,brn->b...n", u, b_sel) * scale
             return y + lo.astype(y.dtype), True
-        return _map_fallback(axis_size, in_batched,
-                             (x, aidx, w, a_stack, b_stack), base)
+        return _vmap_fallback(axis_size, in_batched,
+                              (x, aidx, w, a_stack, b_stack), base)
     return f
 
 
@@ -598,8 +598,8 @@ def _lora_contract_fn(scale: float, has_xd: bool, backend: str):
                                              in_batched[5:])
                 return lora_dual_mt_jvps(x, w, a, ad, b, bd, gy, xdots=xd,
                                          **kw), True
-            return _map_fallback(axis_size, in_batched,
-                                 (gy, x, w, a, b, xd, ad, bd), base)
+            return _vmap_fallback(axis_size, in_batched,
+                                  (gy, x, w, a, b, xd, ad, bd), base)
     else:
         def base(gy, x, w, a, b, ad, bd):
             return lora_dual_mt_jvps(x, w, a, ad[None], b, bd[None], gy,
@@ -612,8 +612,8 @@ def _lora_contract_fn(scale: float, has_xd: bool, backend: str):
             if not any(in_batched[:5]):
                 ad, bd = _stack_tangents(axis_size, (ad, bd), in_batched[5:])
                 return lora_dual_mt_jvps(x, w, a, ad, b, bd, gy, **kw), True
-            return _map_fallback(axis_size, in_batched,
-                                 (gy, x, w, a, b, ad, bd), base)
+            return _vmap_fallback(axis_size, in_batched,
+                                  (gy, x, w, a, b, ad, bd), base)
     return f
 
 
@@ -660,9 +660,9 @@ def _wkv6_contract_fn(has_ud: bool, backend: str):
                     axis_size, (rd, kd, vd, wd, ud), in_batched[6:])
                 return wkv6_scan_mt_jvps(r, k, v, w, u, rd, kd, vd, wd, gy,
                                          ud, interpret=interpret), True
-            return _map_fallback(axis_size, in_batched,
-                                 (gy, r, k, v, w, u, rd, kd, vd, wd, ud),
-                                 base)
+            return _vmap_fallback(axis_size, in_batched,
+                                  (gy, r, k, v, w, u, rd, kd, vd, wd, ud),
+                                  base)
     else:
         def base(gy, r, k, v, w, u, rd, kd, vd, wd):
             return wkv6_scan_mt_jvps(r, k, v, w, u, rd[None], kd[None],
@@ -678,8 +678,8 @@ def _wkv6_contract_fn(has_ud: bool, backend: str):
                     axis_size, (rd, kd, vd, wd), in_batched[6:])
                 return wkv6_scan_mt_jvps(r, k, v, w, u, rd, kd, vd, wd, gy,
                                          interpret=interpret), True
-            return _map_fallback(axis_size, in_batched,
-                                 (gy, r, k, v, w, u, rd, kd, vd, wd), base)
+            return _vmap_fallback(axis_size, in_batched,
+                                  (gy, r, k, v, w, u, rd, kd, vd, wd), base)
     return f
 
 
@@ -723,8 +723,8 @@ def _mamba2_contract_fn(backend: str):
             return mamba2_ops.mamba2_scan_mt_jvps(
                 xdt, bm, cm, dec, xd, bd, cd, dd, gy,
                 interpret=interpret), True
-        return _map_fallback(axis_size, in_batched,
-                             (gy, xdt, bm, cm, dec, xd, bd, cd, dd), base)
+        return _vmap_fallback(axis_size, in_batched,
+                              (gy, xdt, bm, cm, dec, xd, bd, cd, dd), base)
     return f
 
 
@@ -767,8 +767,8 @@ def _swa_contract_fn(window, backend: str):
             return swa_attention_mt_jvps(q, k, v, qd, kd, vd, gy,
                                          window=window,
                                          interpret=interpret), True
-        return _map_fallback(axis_size, in_batched,
-                             (gy, q, k, v, qd, kd, vd), base)
+        return _vmap_fallback(axis_size, in_batched,
+                              (gy, q, k, v, qd, kd, vd), base)
     return f
 
 

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels import mxu_dot
 
 
 def _mt_kernel(*refs, scale: float, n_k: int, n_t: int, has_xdot: bool,
@@ -61,29 +61,27 @@ def _mt_kernel(*refs, scale: float, n_k: int, n_t: int, has_xdot: bool,
     a = a_ref[...]
     # one read of the x/W blocks feeds the primal AND every tangent
     if emit_primal:
-        acc_y[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
-    acc_u[...] += jnp.dot(x, a, preferred_element_type=jnp.float32)
+        acc_y[...] += mxu_dot(x, w)
+    acc_u[...] += mxu_dot(x, a)
     for t in range(n_t):  # static unroll over the tangent axis
-        acc_ud[t] += jnp.dot(x, ad_ref[t],
-                             preferred_element_type=jnp.float32)
+        acc_ud[t] += mxu_dot(x, ad_ref[t])
         if has_xdot:
             xd_t = xd_ref[t]
-            acc_yd[t] += jnp.dot(xd_t, w, preferred_element_type=jnp.float32)
-            acc_ud[t] += jnp.dot(xd_t, a, preferred_element_type=jnp.float32)
+            acc_yd[t] += mxu_dot(xd_t, w)
+            acc_ud[t] += mxu_dot(xd_t, a)
 
     @pl.when(k == n_k - 1)
     def _finish():
         b = b_ref[...].astype(jnp.float32)
         u = acc_u[...]
         if emit_primal:
-            y = acc_y[...] + scale * jnp.dot(
-                u, b, preferred_element_type=jnp.float32)
+            y = acc_y[...] + scale * mxu_dot(u, b)
             y_ref[...] = y.astype(y_ref.dtype)
         for t in range(n_t):
             bd_t = bd_ref[t].astype(jnp.float32)
             yd = scale * (
-                jnp.dot(acc_ud[t], b, preferred_element_type=jnp.float32)
-                + jnp.dot(u, bd_t, preferred_element_type=jnp.float32))
+                mxu_dot(acc_ud[t], b)
+                + mxu_dot(u, bd_t))
             if has_xdot:
                 yd = yd + acc_yd[t]
             yd_ref[t] = yd.astype(yd_ref.dtype)
@@ -91,7 +89,7 @@ def _mt_kernel(*refs, scale: float, n_k: int, n_t: int, has_xdot: bool,
 
 def lora_dual_mt_kernel(x, xdots, w, a, adots, b, bdots, *, scale: float,
                         block_m: int = 128, block_n: int = 128,
-                        block_k: int = 128, interpret: bool = True,
+                        block_k: int = 128, interpret: bool,
                         emit_primal: bool = True):
     """x: (M,K); xdots: (T,M,K) or None; w: (K,N); a/adots: (K,r)/(T,K,r);
     b/bdots: (r,N)/(T,r,N) -> (y (M,N), ydots (T,M,N)), or just ydots when
@@ -151,7 +149,7 @@ def lora_dual_mt_kernel(x, xdots, w, a, adots, b, bdots, *, scale: float,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -169,7 +167,7 @@ def _mt_jvps_kernel(*refs, scale: float, n_k: int, n_t: int, has_xdot: bool):
     in VMEM — so neither a (T, bm, bn) tangent tile nor a (T, bm, bn)
     scratch ever exists. At the last k step the LoRA terms collapse to
     rank-r contractions (z1 = gy @ bᵀ against udots, z2 = uᵀ @ gy against
-    bdots) and the (1, 1, T) per-block partials are written out — the only
+    bdots) and the (T, 1) per-block partials are written out — the only
     HBM the epilogue writes is one scalar per tangent per grid tile.
     """
     refs = list(refs)
@@ -191,48 +189,47 @@ def _mt_jvps_kernel(*refs, scale: float, n_k: int, n_t: int, has_xdot: bool):
 
     x = x_ref[...]
     a = a_ref[...]
-    acc_u[...] += jnp.dot(x, a, preferred_element_type=jnp.float32)
+    acc_u[...] += mxu_dot(x, a)
     if has_xdot:
         gy = gy_ref[...].astype(jnp.float32)
         # ONE frozen-weight GEMM per k step, shared across all T tangents:
         # <gy, xd_t @ w_k> = <gy @ w_kᵀ, xd_t>
-        zw = jnp.dot(gy, w_ref[...].T, preferred_element_type=jnp.float32)
+        zw = mxu_dot(gy, w_ref[...].T)
     for t in range(n_t):  # static unroll over the tangent axis
-        acc_ud[t] += jnp.dot(x, ad_ref[t],
-                             preferred_element_type=jnp.float32)
+        acc_ud[t] += mxu_dot(x, ad_ref[t])
         if has_xdot:
             xd_t = xd_ref[t]
-            acc_ud[t] += jnp.dot(xd_t, a, preferred_element_type=jnp.float32)
-            acc_j[t, 0] += jnp.sum(zw * xd_t.astype(jnp.float32))
+            acc_ud[t] += mxu_dot(xd_t, a)
+            acc_j[t:t + 1, :] += jnp.sum(zw * xd_t.astype(jnp.float32),
+                                         keepdims=True)
 
     @pl.when(k == n_k - 1)
     def _finish():
         gy = gy_ref[...].astype(jnp.float32)
         b = b_ref[...].astype(jnp.float32)
         u = acc_u[...]
-        z1 = jnp.dot(gy, b.T, preferred_element_type=jnp.float32)    # (bm, r)
-        z2 = jnp.dot(u.T, gy, preferred_element_type=jnp.float32)    # (r, bn)
-        parts = []
+        z1 = mxu_dot(gy, b.T)                       # (bm, r)
+        z2 = mxu_dot(u.T, gy)                       # (r, bn)
         for t in range(n_t):
             bd_t = bd_ref[t].astype(jnp.float32)
-            part = scale * (jnp.sum(z1 * acc_ud[t]) + jnp.sum(z2 * bd_t))
+            part = scale * (jnp.sum(z1 * acc_ud[t], keepdims=True)
+                            + jnp.sum(z2 * bd_t, keepdims=True))    # (1, 1)
             if has_xdot:
-                part = part + acc_j[t, 0]
-            parts.append(part)
-        out_ref[0, 0, :] = jnp.stack(parts)
+                part = part + acc_j[t:t + 1, :]
+            out_ref[0, 0, t:t + 1, :] = part
 
 
 def lora_dual_mt_jvps_kernel(x, xdots, w, a, adots, b, bdots, gy, *,
                              scale: float, block_m: int = 128,
                              block_n: int = 128, block_k: int = 128,
-                             interpret: bool = True):
+                             interpret: bool):
     """In-kernel fused jvp contraction: all T scalars <gy, ydot_t> with NO
     (T, M, N) tangent output — the HBM side of the epilogue is one (T,)
     partial per (i, j) grid tile, summed by the caller (ops.py).
 
     x: (M,K); xdots: (T,M,K) or None; w: (K,N); a/adots: (K,r)/(T,K,r);
     b/bdots: (r,N)/(T,r,N); gy: (M,N) -> per-block partials
-    (M/bm, N/bn, T) fp32."""
+    (M/bm, N/bn, T, 1) fp32."""
     M, K = x.shape
     N = w.shape[1]
     r = a.shape[1]
@@ -272,11 +269,13 @@ def lora_dual_mt_jvps_kernel(x, xdots, w, a, adots, b, bdots, gy, *,
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, T), lambda i, j, k: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((M // block_m, N // block_n, T),
+        # (T, 1) trailing dims span the whole array: a (1, T) block of a
+        # (N/bn, T) array would break the TPU's (8, 128) block-tiling rule
+        out_specs=pl.BlockSpec((1, 1, T, 1), lambda i, j, k: (i, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((M // block_m, N // block_n, T, 1),
                                        jnp.float32),
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -299,27 +298,31 @@ def _multi_kernel(x_ref, idx_ref, w_ref, a_ref, b_ref, y_ref, acc_y, acc_u,
         acc_u[...] = jnp.zeros_like(acc_u)
 
     x = x_ref[...]
-    acc_y[...] += jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
+    acc_y[...] += mxu_dot(x, w_ref[...])
     for p in range(n_pages):  # static unroll over resident adapter pages
-        acc_u[p] += jnp.dot(x, a_ref[p], preferred_element_type=jnp.float32)
+        acc_u[p] += mxu_dot(x, a_ref[p])
 
     @pl.when(k == n_k - 1)
     def _finish():
         idx = idx_ref[...]                             # (bm, 1) int32
-        y = acc_y[...]
+        lo = jnp.zeros_like(acc_y)
         for p in range(n_pages):
             bp = b_ref[p].astype(jnp.float32)
-            yp = scale * jnp.dot(acc_u[p], bp,
-                                 preferred_element_type=jnp.float32)
+            yp = scale * mxu_dot(acc_u[p], bp)
             # adding the zero-masked other pages is exact (x + 0.0 == x):
             # no cross-adapter contamination, each row sees only its page
-            y = y + jnp.where(idx == p, yp, 0.0)
-        y_ref[...] = y.astype(y_ref.dtype)
+            lo = lo + jnp.where(idx == p, yp, 0.0)
+        # both terms are rounded to the output dtype before the add, as the
+        # single-adapter projection rounds them (x @ W + lora.astype(dtype))
+        dt = y_ref.dtype
+        y = (acc_y[...].astype(dt).astype(jnp.float32)
+             + lo.astype(dt).astype(jnp.float32))
+        y_ref[...] = y.astype(dt)
 
 
 def lora_dual_multi_kernel(x, idx, w, a_stack, b_stack, *, scale: float,
                            block_m: int = 128, block_n: int = 128,
-                           block_k: int = 128, interpret: bool = True):
+                           block_k: int = 128, interpret: bool):
     """x: (M,K); idx: (M,1) int32 adapter-page per row; w: (K,N);
     a_stack: (P,K,r); b_stack: (P,r,N) -> y (M,N).
 
@@ -356,7 +359,7 @@ def lora_dual_multi_kernel(x, idx, w, a_stack, b_stack, *, scale: float,
             pltpu.VMEM((block_m, block_n), jnp.float32),
             pltpu.VMEM((P, block_m, r), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, idx, w, a_stack, b_stack)
@@ -364,7 +367,7 @@ def lora_dual_multi_kernel(x, idx, w, a_stack, b_stack, *, scale: float,
 
 def lora_dual_kernel(x, xdot, w, a, adot, b, bdot, *, scale: float,
                      block_m: int = 128, block_n: int = 128,
-                     block_k: int = 128, interpret: bool = True):
+                     block_k: int = 128, interpret: bool):
     """Single-tangent compatibility wrapper: T=1 slice of the mt kernel.
 
     x/xdot: (M,K); w: (K,N); a/adot: (K,r); b/bdot: (r,N) -> (y, ydot)."""

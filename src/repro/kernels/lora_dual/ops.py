@@ -22,6 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import sum_partials
 from repro.kernels.lora_dual.kernel import (
     lora_dual_kernel,
     lora_dual_mt_jvps_kernel,
@@ -42,8 +43,8 @@ def _pad_to(x, mult, axis):
 @functools.partial(jax.jit, static_argnames=("scale", "block_m", "block_n",
                                              "block_k", "interpret"))
 def lora_dual(x, xdot, w, a, adot, b, bdot, scale: float = 1.0,
-              block_m: int = 128, block_n: int = 128, block_k: int = 128,
-              interpret: bool = True):
+              block_m: int = 128, block_n: int = 128, block_k: int = 128, *,
+              interpret: bool):
     """Fused y = x@W + s(x@A)@B and its jvp. x may have leading batch dims."""
     y, ydots = lora_dual_mt(x, xdot[None], w, a, adot[None], b, bdot[None],
                             scale=scale, block_m=block_m, block_n=block_n,
@@ -54,8 +55,8 @@ def lora_dual(x, xdot, w, a, adot, b, bdot, scale: float = 1.0,
 @functools.partial(jax.jit, static_argnames=("scale", "block_m", "block_n",
                                              "block_k", "interpret"))
 def lora_dual_mt(x, xdots, w, a, adots, b, bdots, scale: float = 1.0,
-                 block_m: int = 128, block_n: int = 128, block_k: int = 128,
-                 interpret: bool = True):
+                 block_m: int = 128, block_n: int = 128, block_k: int = 128, *,
+                 interpret: bool):
     """Multi-tangent fused pass. x: (..., K); xdots: (T, ..., K) or None;
     adots: (T, K, r); bdots: (T, r, N) -> (y (..., N), ydots (T, ..., N))."""
     batch_shape = x.shape[:-1]
@@ -89,7 +90,7 @@ def lora_dual_mt(x, xdots, w, a, adots, b, bdots, scale: float = 1.0,
                                              "block_k", "interpret"))
 def lora_dual_mt_tangents(x, xdots, w, a, adots, b, bdots, scale: float = 1.0,
                           block_m: int = 128, block_n: int = 128,
-                          block_k: int = 128, interpret: bool = True):
+                          block_k: int = 128, *, interpret: bool):
     """Tangent-only fused pass -> ydots (T, ..., N). Same contract as
     ``lora_dual_mt`` but skips the primal output — the AD dispatch rule uses
     this so its primal stays a pure function of primal inputs (required for
@@ -119,7 +120,7 @@ def lora_dual_mt_tangents(x, xdots, w, a, adots, b, bdots, scale: float = 1.0,
                                              "block_k", "interpret"))
 def lora_dual_multi(x, idx, w, a_stack, b_stack, scale: float = 1.0,
                     block_m: int = 128, block_n: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, *, interpret: bool):
     """Multi-adapter fused projection: each batch row reads its own LoRA
     page, one pass over the shared frozen W. x: (..., K); idx: adapter-page
     indices broadcastable to x.shape[:-1] (typically (B,) over a (B, S, K)
@@ -149,7 +150,7 @@ def lora_dual_multi(x, idx, w, a_stack, b_stack, scale: float = 1.0,
 def lora_dual_mt_jvps(x, w, a, adots, b, bdots, gy, scale: float = 1.0,
                       xdots=None, impl: str = "reassoc",
                       block_m: int = 128, block_n: int = 128,
-                      block_k: int = 128, interpret: bool = True):
+                      block_k: int = 128, *, interpret: bool):
     """All T jvp scalars <gy, ydot_t> via the fused contraction epilogue.
 
     Never materializes a (T, M, N) tangent stack: the frozen-weight GEMM
@@ -185,7 +186,7 @@ def lora_dual_mt_jvps(x, w, a, adots, b, bdots, gy, scale: float = 1.0,
             x2, xd2, wp, ap, adp, bp, bdp, gy2, scale=scale,
             block_m=block_m, block_n=block_n, block_k=block_k,
             interpret=interpret)
-        return parts.sum(axis=(0, 1))
+        return sum_partials(parts)
 
     x = x.reshape(-1, x.shape[-1])
     gy = gy.reshape(-1, gy.shape[-1]).astype(jnp.float32)

@@ -29,7 +29,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+
+def _decay_spec(block_s):
+    """The per-(row, token) scalar decay rides as (rows, S, 1): a
+    (1, block_s) block of a (rows, S) array breaks the TPU's (8, 128)
+    block-tiling rule, while (1, block_s, 1) tiles S on sublanes."""
+    return pl.BlockSpec((1, block_s, 1), lambda b, s: (b, s, 0))
 
 
 def _kernel(x_ref, b_ref, c_ref, d_ref, y_ref, state_scr, *, block_s: int):
@@ -43,7 +48,7 @@ def _kernel(x_ref, b_ref, c_ref, d_ref, y_ref, state_scr, *, block_s: int):
         xt = x_ref[0, t, :]                         # (hd,)
         bt = b_ref[0, t, :]                         # (N,)
         ct = c_ref[0, t, :]
-        dct = d_ref[0, t]                           # per-head scalar decay
+        dct = d_ref[0, t, 0]                        # per-head scalar decay
         h = dct * state_scr[...] + xt[:, None] * bt[None, :]
         y_ref[0, t, :] = (h * ct[None, :]).sum(axis=1).astype(y_ref.dtype)
         state_scr[...] = h
@@ -53,7 +58,7 @@ def _kernel(x_ref, b_ref, c_ref, d_ref, y_ref, state_scr, *, block_s: int):
 
 
 def mamba2_scan_kernel(xdt, bmat, cmat, decay, *, n_heads: int,
-                       block_s: int = 64, interpret=True):
+                       block_s: int = 64, interpret: bool):
     """xdt: (BH, S, hd) fp32; bmat,cmat: (B, S, N); decay: (BH, S).
     Returns y (BH, S, hd) fp32. ``n_heads`` folds grid row bh back to batch
     row bh // n_heads for the shared B/C streams."""
@@ -71,15 +76,15 @@ def mamba2_scan_kernel(xdt, bmat, cmat, decay, *, n_heads: int,
             pl.BlockSpec((1, block_s, hd), lambda b, s: (b, s, 0)),
             bc_spec,
             bc_spec,
-            pl.BlockSpec((1, block_s), lambda b, s: (b, s)),
+            _decay_spec(block_s),
         ],
         out_specs=pl.BlockSpec((1, block_s, hd), lambda b, s: (b, s, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((hd, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(xdt, bmat, cmat, decay)
+    )(xdt, bmat, cmat, decay[..., None])
 
 
 def _mt_kernel(x_ref, b_ref, c_ref, d_ref, xd_ref, bd_ref, cd_ref, dd_ref,
@@ -99,7 +104,7 @@ def _mt_kernel(x_ref, b_ref, c_ref, d_ref, xd_ref, bd_ref, cd_ref, dd_ref,
         xt = x_ref[0, t, :]                         # (hd,)
         bt = b_ref[0, t, :]                         # (N,)
         ct = c_ref[0, t, :]
-        dct = d_ref[0, t]
+        dct = d_ref[0, t, 0]
         s = state_scr[...]                          # (hd, N)
         h = dct * s + xt[:, None] * bt[None, :]
         if emit_primal:
@@ -111,7 +116,7 @@ def _mt_kernel(x_ref, b_ref, c_ref, d_ref, xd_ref, bd_ref, cd_ref, dd_ref,
             xdt_t = xd_ref[tau, 0, t, :]
             bdt = bd_ref[tau, 0, t, :]
             cdt = cd_ref[tau, 0, t, :]
-            ddt = dd_ref[tau, 0, t]
+            ddt = dd_ref[tau, 0, t, 0]
             sd = state_d_scr[tau]                   # (hd, N)
             hd_t = (ddt * s + dct * sd + xdt_t[:, None] * bt[None, :]
                     + xt[:, None] * bdt[None, :])
@@ -131,7 +136,7 @@ def _mt_jvps_kernel(x_ref, b_ref, c_ref, d_ref, xd_ref, bd_ref, cd_ref,
     """Contraction epilogue: the same primal-state / tangent-state walk as
     ``_mt_kernel``, but each per-token ydot_t is contracted against the
     incoming gy token on the spot — accumulated into a (T, hd) VMEM partial
-    — instead of being written to HBM. Only a (1, T) per-row partial leaves
+    — instead of being written to HBM. Only a (T, 1) per-row partial leaves
     the kernel at the last sequence block."""
     si = pl.program_id(1)
 
@@ -145,7 +150,7 @@ def _mt_jvps_kernel(x_ref, b_ref, c_ref, d_ref, xd_ref, bd_ref, cd_ref,
         xt = x_ref[0, t, :]                         # (hd,)
         bt = b_ref[0, t, :]                         # (N,)
         ct = c_ref[0, t, :]
-        dct = d_ref[0, t]
+        dct = d_ref[0, t, 0]
         gt = gy_ref[0, t, :].astype(jnp.float32)
         s = state_scr[...]                          # (hd, N)
         h = dct * s + xt[:, None] * bt[None, :]
@@ -156,7 +161,7 @@ def _mt_jvps_kernel(x_ref, b_ref, c_ref, d_ref, xd_ref, bd_ref, cd_ref,
             xdt_t = xd_ref[tau, 0, t, :]
             bdt = bd_ref[tau, 0, t, :]
             cdt = cd_ref[tau, 0, t, :]
-            ddt = dd_ref[tau, 0, t]
+            ddt = dd_ref[tau, 0, t, 0]
             sd = state_d_scr[tau]                   # (hd, N)
             hd_t = (ddt * s + dct * sd + xdt_t[:, None] * bt[None, :]
                     + xt[:, None] * bdt[None, :])
@@ -171,17 +176,17 @@ def _mt_jvps_kernel(x_ref, b_ref, c_ref, d_ref, xd_ref, bd_ref, cd_ref,
 
     @pl.when(si == n_s - 1)
     def _finish():
-        out_ref[0, :] = acc_j[...].sum(axis=1)
+        out_ref[0] = acc_j[...].sum(axis=1, keepdims=True)
 
 
 def mamba2_scan_mt_jvps_kernel(xdt, bmat, cmat, decay, xdtds, bds, cds,
                                decayds, gy, *, n_heads: int, block_s: int = 64,
-                               interpret=True):
+                               interpret: bool):
     """Fused jvp-contraction epilogue of the multi-tangent Mamba2
     recurrence: all T scalars <gy, ydot_t> with NO (T, BH, S, hd) tangent
     output — the per-token ydots are contracted against gy in VMEM as the
-    state walk produces them. Returns per-row partials (BH, T) fp32, summed
-    by the caller (ops.py). Same operand contract as
+    state walk produces them. Returns per-row partials (BH, T, 1) fp32,
+    summed by the caller (ops.py). Same operand contract as
     ``mamba2_scan_mt_kernel`` plus gy: (BH, S, hd)."""
     BH, S, hd = xdt.shape
     N = bmat.shape[-1]
@@ -199,28 +204,29 @@ def mamba2_scan_mt_jvps_kernel(xdt, bmat, cmat, decay, xdtds, bds, cds,
                             lambda b, s: (0, b // n_heads, s, 0))
     in_specs = [
         seq_spec, bc_spec, bc_spec,
-        pl.BlockSpec((1, block_s), lambda b, s: (b, s)),
+        _decay_spec(block_s),
         seq_spec_t, bcd_spec, bcd_spec,
-        pl.BlockSpec((T, 1, block_s), lambda b, s: (0, b, s)),
+        pl.BlockSpec((T, 1, block_s, 1), lambda b, s: (0, b, s, 0)),
         seq_spec,                                   # gy
     ]
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, T), lambda b, s: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, T), jnp.float32),
+        out_specs=pl.BlockSpec((1, T, 1), lambda b, s: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((BH, T, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((hd, N), jnp.float32),
                         pltpu.VMEM((T, hd, N), jnp.float32),
                         pltpu.VMEM((T, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(xdt, bmat, cmat, decay, xdtds, bds, cds, decayds, gy)
+    )(xdt, bmat, cmat, decay[..., None], xdtds, bds, cds,
+      decayds[..., None], gy)
 
 
 def mamba2_scan_mt_kernel(xdt, bmat, cmat, decay, xdtds, bds, cds, decayds,
-                          *, n_heads: int, block_s: int = 64, interpret=True,
+                          *, n_heads: int, block_s: int = 64, interpret: bool,
                           emit_primal: bool = True):
     """Multi-tangent Mamba2 recurrence: one pass over the primal operands
     produces y plus all T ydots.
@@ -244,9 +250,9 @@ def mamba2_scan_mt_kernel(xdt, bmat, cmat, decay, xdtds, bds, cds, decayds,
                             lambda b, s: (0, b // n_heads, s, 0))
     in_specs = [
         seq_spec, bc_spec, bc_spec,
-        pl.BlockSpec((1, block_s), lambda b, s: (b, s)),
+        _decay_spec(block_s),
         seq_spec_t, bcd_spec, bcd_spec,
-        pl.BlockSpec((T, 1, block_s), lambda b, s: (0, b, s)),
+        pl.BlockSpec((T, 1, block_s, 1), lambda b, s: (0, b, s, 0)),
     ]
     out_specs = [seq_spec_t]
     out_shape = [jax.ShapeDtypeStruct((T, BH, S, hd), jnp.float32)]
@@ -261,8 +267,9 @@ def mamba2_scan_mt_kernel(xdt, bmat, cmat, decay, xdtds, bds, cds, decayds,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((hd, N), jnp.float32),
                         pltpu.VMEM((T, hd, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(xdt, bmat, cmat, decay, xdtds, bds, cds, decayds)
+    )(xdt, bmat, cmat, decay[..., None], xdtds, bds, cds,
+      decayds[..., None])
     return outs if emit_primal else outs[0]

@@ -24,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import sum_partials
 from repro.kernels.mamba2_scan.kernel import (
     mamba2_scan_kernel,
     mamba2_scan_mt_jvps_kernel,
@@ -69,8 +70,8 @@ def _layout_t(xdtds, bds, cds, decayds, T, B, S, H, hd, pad):
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
-def mamba2_scan(xdt, bmat, cmat, decay, block_s: int = 64,
-                interpret: bool = True):
+def mamba2_scan(xdt, bmat, cmat, decay, block_s: int = 64, *,
+                interpret: bool):
     """xdt: (B,S,H,hd); bmat,cmat: (B,S,N); decay: (B,S,H). Returns
     y (B,S,H,hd) fp32. Fresh state per call (training semantics); the
     decode path keeps its state outside and uses the jnp reference."""
@@ -83,7 +84,7 @@ def mamba2_scan(xdt, bmat, cmat, decay, block_s: int = 64,
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def mamba2_scan_mt(xdt, bmat, cmat, decay, xdtds, bds, cds, decayds,
-                   block_s: int = 64, interpret: bool = True):
+                   block_s: int = 64, *, interpret: bool):
     """Multi-tangent fused pass -> (y (B,S,H,hd), ydots (T,B,S,H,hd))."""
     T = xdtds.shape[0]
     (xb, bb, cb, db), (B, S, H, hd, bs, pad) = _layout(
@@ -99,7 +100,7 @@ def mamba2_scan_mt(xdt, bmat, cmat, decay, xdtds, bds, cds, decayds,
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def mamba2_scan_mt_tangents(xdt, bmat, cmat, decay, xdtds, bds, cds, decayds,
-                            block_s: int = 64, interpret: bool = True):
+                            block_s: int = 64, *, interpret: bool):
     """Tangent-only fused pass -> ydots (T,B,S,H,hd). Same contract as
     ``mamba2_scan_mt`` but skips the primal y output (the primal state walk
     still runs in-kernel — the tangent recurrence needs h_{t-1})."""
@@ -116,7 +117,7 @@ def mamba2_scan_mt_tangents(xdt, bmat, cmat, decay, xdtds, bds, cds, decayds,
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def mamba2_scan_mt_jvps(xdt, bmat, cmat, decay, xdtds, bds, cds, decayds, gy,
-                        block_s: int = 64, interpret: bool = True):
+                        block_s: int = 64, *, interpret: bool):
     """Fused jvp-contraction epilogue -> jvps (T,) fp32 = <gy, ydot_t>.
 
     Same operand contract as ``mamba2_scan_mt`` plus the output cotangent
@@ -134,4 +135,4 @@ def mamba2_scan_mt_jvps(xdt, bmat, cmat, decay, xdtds, bds, cds, decayds, gy,
     parts = mamba2_scan_mt_jvps_kernel(xb, bb, cb, db, xdb, bdb, cdb, ddb,
                                        gyb, n_heads=H, block_s=bs,
                                        interpret=interpret)
-    return parts.sum(axis=0)
+    return sum_partials(parts)

@@ -35,7 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels import mxu_dot
+
 
 NEG_INF = -1e30
 
@@ -102,7 +103,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     keep = _keep_mask(qi, step, block_q=block_q, block_k=block_k,
                       window=window, n_k_total=n_k_total, banded=banded)
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    s = mxu_dot(q, k.T) * scale
     s = jnp.where(keep, s, NEG_INF)
 
     m_prev = m_scr[...]                                # (block_q, 1)
@@ -111,8 +112,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     # explicit keep-gating: exp(NEG_INF - NEG_INF) would be 1, not 0
     p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
     l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    acc_scr[...] = acc_scr[...] * alpha + mxu_dot(
+        p.astype(v.dtype), v)
     m_scr[...] = m_new
 
     @pl.when(step == n_kv_steps - 1)
@@ -138,7 +139,7 @@ def _plan(S, hd, window, block_q, block_k, scale):
 
 
 def swa_attention_kernel(q, k, v, *, window, block_q=128, block_k=128,
-                         interpret=True, scale=None, n_heads=None,
+                         interpret: bool, scale=None, n_heads=None,
                          kv_groups=1):
     """q: (B*H, S, hd); k,v: (B*KV, S, hd) -> out (B*H, S, hd). Causal;
     window may be None. ``scale`` overrides 1/sqrt(hd) (needed when hd was
@@ -178,7 +179,7 @@ def swa_attention_kernel(q, k, v, *, window, block_q=128, block_k=128,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -209,7 +210,7 @@ def _mt_kernel(q_ref, k_ref, v_ref, qd_ref, kd_ref, vd_ref, *rest,
     keep = _keep_mask(qi, step, block_q=block_q, block_k=block_k,
                       window=window, n_k_total=n_k_total, banded=banded)
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    s = mxu_dot(q, k.T) * scale
     s = jnp.where(keep, s, NEG_INF)
 
     m_prev = m_scr[...]                                # (block_q, 1)
@@ -217,8 +218,8 @@ def _mt_kernel(q_ref, k_ref, v_ref, qd_ref, kd_ref, vd_ref, *rest,
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
     l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    acc_scr[...] = acc_scr[...] * alpha + mxu_dot(
+        p.astype(v.dtype), v)
     m_scr[...] = m_new
 
     for tau in range(n_t):                             # static unroll over T
@@ -226,14 +227,12 @@ def _mt_kernel(q_ref, k_ref, v_ref, qd_ref, kd_ref, vd_ref, *rest,
         kd = kd_ref[tau, 0]
         vd = vd_ref[tau, 0]
         # score tangent; p==0 lanes kill any out-of-band sd values
-        sd = (jnp.dot(qd, k.T, preferred_element_type=jnp.float32)
-              + jnp.dot(q, kd.T, preferred_element_type=jnp.float32)) * scale
+        sd = (mxu_dot(qd, k.T) + mxu_dot(q, kd.T)) * scale
         psd = p * sd
         mu_d_scr[tau] = mu_d_scr[tau] * alpha + psd.sum(axis=-1, keepdims=True)
         acc_d_scr[tau] = acc_d_scr[tau] * alpha + (
-            jnp.dot(psd.astype(v.dtype), v, preferred_element_type=jnp.float32)
-            + jnp.dot(p.astype(vd.dtype), vd,
-                      preferred_element_type=jnp.float32))
+            mxu_dot(psd.astype(v.dtype), v)
+            + mxu_dot(p.astype(vd.dtype), vd))
 
     @pl.when(step == n_kv_steps - 1)
     def _finish():
@@ -253,7 +252,7 @@ def _mt_jvps_kernel(q_ref, k_ref, v_ref, qd_ref, kd_ref, vd_ref, gy_ref,
     """Contraction epilogue: the same online-softmax walk (primal + T
     tangent accumulators) as ``_mt_kernel``, but the per-query-block outd_t
     tiles are contracted against the incoming gy tile at the final kv step
-    instead of being written out — only (1, 1, T) per-block partials reach
+    instead of being written out — only (T, 1) per-block partials reach
     HBM."""
     qi = pl.program_id(1)
     step = pl.program_id(2)
@@ -273,7 +272,7 @@ def _mt_jvps_kernel(q_ref, k_ref, v_ref, qd_ref, kd_ref, vd_ref, gy_ref,
     keep = _keep_mask(qi, step, block_q=block_q, block_k=block_k,
                       window=window, n_k_total=n_k_total, banded=banded)
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    s = mxu_dot(q, k.T) * scale
     s = jnp.where(keep, s, NEG_INF)
 
     m_prev = m_scr[...]                                # (block_q, 1)
@@ -281,42 +280,39 @@ def _mt_jvps_kernel(q_ref, k_ref, v_ref, qd_ref, kd_ref, vd_ref, gy_ref,
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
     l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    acc_scr[...] = acc_scr[...] * alpha + mxu_dot(
+        p.astype(v.dtype), v)
     m_scr[...] = m_new
 
     for tau in range(n_t):                             # static unroll over T
         qd = qd_ref[tau, 0]
         kd = kd_ref[tau, 0]
         vd = vd_ref[tau, 0]
-        sd = (jnp.dot(qd, k.T, preferred_element_type=jnp.float32)
-              + jnp.dot(q, kd.T, preferred_element_type=jnp.float32)) * scale
+        sd = (mxu_dot(qd, k.T) + mxu_dot(q, kd.T)) * scale
         psd = p * sd
         mu_d_scr[tau] = mu_d_scr[tau] * alpha + psd.sum(axis=-1, keepdims=True)
         acc_d_scr[tau] = acc_d_scr[tau] * alpha + (
-            jnp.dot(psd.astype(v.dtype), v, preferred_element_type=jnp.float32)
-            + jnp.dot(p.astype(vd.dtype), vd,
-                      preferred_element_type=jnp.float32))
+            mxu_dot(psd.astype(v.dtype), v)
+            + mxu_dot(p.astype(vd.dtype), vd))
 
     @pl.when(step == n_kv_steps - 1)
     def _finish():
         gy = gy_ref[0].astype(jnp.float32)             # (block_q, hd)
         l = jnp.maximum(l_scr[...], 1e-30)
         out = acc_scr[...] / l
-        parts = []
         for tau in range(n_t):
             outd = acc_d_scr[tau] / l - (mu_d_scr[tau] / l) * out
-            parts.append(jnp.sum(gy * outd))           # contract, never store
-        out_ref[0, 0, :] = jnp.stack(parts)
+            # contract, never store
+            out_ref[0, 0, tau:tau + 1, :] = jnp.sum(gy * outd, keepdims=True)
 
 
 def swa_attention_mt_jvps_kernel(q, k, v, qds, kds, vds, gy, *, window,
-                                 block_q=128, block_k=128, interpret=True,
+                                 block_q=128, block_k=128, interpret: bool,
                                  scale=None, n_heads=None, kv_groups=1):
     """Fused jvp-contraction epilogue of multi-tangent flash SWA: all T
     scalars <gy, outd_t> with NO (T, B*H, S, hd) tangent output. Same
     operand contract as ``swa_attention_mt_kernel`` plus gy: (B*H, S, hd);
-    returns per-block partials (B*H, S/block_q, T) fp32, summed by the
+    returns per-block partials (B*H, S/block_q, T, 1) fp32, summed by the
     caller (ops.py)."""
     BH, S, hd = q.shape
     T = qds.shape[0]
@@ -347,8 +343,11 @@ def swa_attention_mt_jvps_kernel(q, k, v, qds, kds, vds, gy, *, window,
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec, qd_spec, kvd_spec, kvd_spec,
                   q_spec],
-        out_specs=pl.BlockSpec((1, 1, T), lambda b, i, s: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, S // block_q, T), jnp.float32),
+        # (T, 1) trailing dims span the whole array: a (1, T) block of a
+        # (S/block_q, T) array would break the TPU's (8, 128) tiling rule
+        out_specs=pl.BlockSpec((1, 1, T, 1), lambda b, i, s: (b, i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((BH, S // block_q, T, 1),
+                                       jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -356,14 +355,14 @@ def swa_attention_mt_jvps_kernel(q, k, v, qds, kds, vds, gy, *, window,
             pltpu.VMEM((T, block_q, 1), jnp.float32),
             pltpu.VMEM((T, block_q, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, qds, kds, vds, gy)
 
 
 def swa_attention_mt_kernel(q, k, v, qds, kds, vds, *, window, block_q=128,
-                            block_k=128, interpret=True, scale=None,
+                            block_k=128, interpret: bool, scale=None,
                             n_heads=None, kv_groups=1, emit_primal=True):
     """Multi-tangent flash SWA: q/k/v as in ``swa_attention_kernel``;
     qds: (T, B*H, S, hd), kds/vds: (T, B*KV, S, hd). Returns
@@ -412,7 +411,7 @@ def swa_attention_mt_kernel(q, k, v, qds, kds, vds, *, window, block_q=128,
             pltpu.VMEM((T, block_q, 1), jnp.float32),
             pltpu.VMEM((T, block_q, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, qds, kds, vds)

@@ -28,6 +28,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import sum_partials
 from repro.kernels.swa_attention.kernel import (
     swa_attention_kernel,
     swa_attention_mt_jvps_kernel,
@@ -69,7 +70,7 @@ def _pad_seq(t, pad_s):
 @functools.partial(jax.jit, static_argnames=("window", "block_q", "block_k",
                                              "interpret", "force_pad_hd"))
 def swa_attention(q, k, v, window=None, block_q=128, block_k=128,
-                  interpret=True, force_pad_hd=False):
+                  *, interpret: bool, force_pad_hd=False):
     """q: (B,H,S,hd); k,v: (B,KV,S,hd) with H % KV == 0. Causal SWA."""
     B, H, S, hd = q.shape
     KV = k.shape[1]
@@ -104,7 +105,7 @@ def _mt_layout(q, k, v, qds, kds, vds, pad_hd, pad_s):
 @functools.partial(jax.jit, static_argnames=("window", "block_q", "block_k",
                                              "interpret", "force_pad_hd"))
 def swa_attention_mt(q, k, v, qds, kds, vds, window=None, block_q=128,
-                     block_k=128, interpret=True, force_pad_hd=False):
+                     block_k=128, *, interpret: bool, force_pad_hd=False):
     """Multi-tangent fused pass -> (out (B,H,S,hd), outds (T,B,H,S,hd))."""
     bq, bk, pad_s = _block_plan(q.shape[-2], block_q, block_k)
     pad_hd = _pad_plan(q.shape[-1], interpret, force_pad_hd)
@@ -122,7 +123,7 @@ def swa_attention_mt(q, k, v, qds, kds, vds, window=None, block_q=128,
 @functools.partial(jax.jit, static_argnames=("window", "block_q", "block_k",
                                              "interpret", "force_pad_hd"))
 def swa_attention_mt_tangents(q, k, v, qds, kds, vds, window=None,
-                              block_q=128, block_k=128, interpret=True,
+                              block_q=128, block_k=128, *, interpret: bool,
                               force_pad_hd=False):
     """Tangent-only fused pass -> outds (T,B,H,S,hd). Same contract as
     ``swa_attention_mt`` but skips the primal output (the AD dispatch rule
@@ -142,7 +143,7 @@ def swa_attention_mt_tangents(q, k, v, qds, kds, vds, window=None,
 @functools.partial(jax.jit, static_argnames=("window", "block_q", "block_k",
                                              "interpret", "force_pad_hd"))
 def swa_attention_mt_jvps(q, k, v, qds, kds, vds, gy, window=None,
-                          block_q=128, block_k=128, interpret=True,
+                          block_q=128, block_k=128, *, interpret: bool,
                           force_pad_hd=False):
     """Fused jvp-contraction epilogue -> jvps (T,) fp32 = <gy, outd_t>.
 
@@ -162,4 +163,4 @@ def swa_attention_mt_jvps(q, k, v, qds, kds, vds, gy, window=None,
         qb, kb, vb, qdb, kdb, vdb, gyb, window=window, block_q=bq,
         block_k=bk, interpret=interpret,
         scale=1.0 / float(hd) ** 0.5, n_heads=H, kv_groups=H // KV)
-    return parts.sum(axis=(0, 1))
+    return sum_partials(parts)

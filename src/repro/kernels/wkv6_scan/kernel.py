@@ -19,7 +19,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+
+def _rows(u):
+    """(..., rows, hd) -> (..., rows, 1, hd). A (1, hd) block of a
+    (rows, hd) array breaks the TPU's (8, 128) block-tiling rule; a
+    (1, 1, hd) block spans the full trailing dims of the reshaped array."""
+    return u.reshape(u.shape[:-1] + (1, u.shape[-1]))
+
+
+def _u_spec(hd):
+    return pl.BlockSpec((1, 1, hd), lambda b, s: (b, 0, 0))
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, state_scr,
@@ -30,7 +39,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, state_scr,
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    u = u_ref[0]                                    # (1, hd) -> (hd,) via [0]
+    u = u_ref[0, 0]                                 # (1, 1, hd) -> (hd,)
 
     def step(t, _):
         rt = r_ref[0, t, :]                         # (hd,)
@@ -47,7 +56,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, state_scr,
     jax.lax.fori_loop(0, block_s, step, ())
 
 
-def wkv6_scan_kernel(r, k, v, w, u, *, block_s: int = 64, interpret=True):
+def wkv6_scan_kernel(r, k, v, w, u, *, block_s: int = 64, interpret: bool):
     """r,k,v,w: (BH, S, hd) fp32; u: (BH, hd). Returns y (BH, S, hd)."""
     BH, S, hd = r.shape
     assert S % block_s == 0
@@ -61,15 +70,15 @@ def wkv6_scan_kernel(r, k, v, w, u, *, block_s: int = 64, interpret=True):
             pl.BlockSpec((1, block_s, hd), lambda b, s: (b, s, 0)),
             pl.BlockSpec((1, block_s, hd), lambda b, s: (b, s, 0)),
             pl.BlockSpec((1, block_s, hd), lambda b, s: (b, s, 0)),
-            pl.BlockSpec((1, hd), lambda b, s: (b, 0)),
+            _u_spec(hd),
         ],
         out_specs=pl.BlockSpec((1, block_s, hd), lambda b, s: (b, s, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(r, k, v, w, u)
+    )(r, k, v, w, _rows(u))
 
 
 def _mt_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, rd_ref, kd_ref, vd_ref,
@@ -87,7 +96,7 @@ def _mt_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, rd_ref, kd_ref, vd_ref,
         state_scr[...] = jnp.zeros_like(state_scr)
         state_d_scr[...] = jnp.zeros_like(state_d_scr)
 
-    u = u_ref[0]                                    # (hd,)
+    u = u_ref[0, 0]                                 # (hd,)
 
     def step(t, _):
         rt = r_ref[0, t, :]                         # (hd,)
@@ -112,7 +121,7 @@ def _mt_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, rd_ref, kd_ref, vd_ref,
             kvd = kdt[:, None] * vt[None, :] + kt[:, None] * vdt[None, :]
             bonus_d = u[:, None] * kvd
             if has_ud:
-                bonus_d = bonus_d + ud_ref[tau, 0][:, None] * kv
+                bonus_d = bonus_d + ud_ref[tau, 0, 0][:, None] * kv
             ydt = (((sd + bonus_d) * rt[:, None]).sum(axis=0)
                    + ((s + u[:, None] * kv) * rdt[:, None]).sum(axis=0))
             state_d_scr[tau] = wdt[:, None] * s + wt[:, None] * sd + kvd
@@ -129,7 +138,7 @@ def _mt_jvps_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, rd_ref, kd_ref,
     """Contraction epilogue: the same primal-state / tangent-state walk as
     ``_mt_kernel``, but each per-token ydot_t is contracted against the
     incoming gy token on the spot — accumulated into a (T, hd) VMEM partial
-    — instead of being written to HBM. Only a (1, T) per-row partial leaves
+    — instead of being written to HBM. Only a (T, 1) per-row partial leaves
     the kernel at the last sequence block."""
     rest = list(rest)
     ud_ref = rest.pop(0) if has_ud else None
@@ -144,7 +153,7 @@ def _mt_jvps_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, rd_ref, kd_ref,
         state_d_scr[...] = jnp.zeros_like(state_d_scr)
         acc_j[...] = jnp.zeros_like(acc_j)
 
-    u = u_ref[0]                                    # (hd,)
+    u = u_ref[0, 0]                                 # (hd,)
 
     def step(t, _):
         rt = r_ref[0, t, :]                         # (hd,)
@@ -163,7 +172,7 @@ def _mt_jvps_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, rd_ref, kd_ref,
             kvd = kdt[:, None] * vt[None, :] + kt[:, None] * vdt[None, :]
             bonus_d = u[:, None] * kvd
             if has_ud:
-                bonus_d = bonus_d + ud_ref[tau, 0][:, None] * kv
+                bonus_d = bonus_d + ud_ref[tau, 0, 0][:, None] * kv
             ydt = (((sd + bonus_d) * rt[:, None]).sum(axis=0)
                    + ((s + u[:, None] * kv) * rdt[:, None]).sum(axis=0))
             state_d_scr[tau] = wdt[:, None] * s + wt[:, None] * sd + kvd
@@ -175,15 +184,15 @@ def _mt_jvps_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, rd_ref, kd_ref,
 
     @pl.when(si == n_s - 1)
     def _finish():
-        out_ref[0, :] = acc_j[...].sum(axis=1)
+        out_ref[0] = acc_j[...].sum(axis=1, keepdims=True)
 
 
 def wkv6_scan_mt_jvps_kernel(r, k, v, w, u, rds, kds, vds, wds, gy, uds=None,
-                             *, block_s: int = 64, interpret=True):
+                             *, block_s: int = 64, interpret: bool):
     """Fused jvp-contraction epilogue of the multi-tangent WKV recurrence:
     all T scalars <gy, ydot_t> with NO (T, BH, S, hd) tangent output — the
     per-token ydots are contracted against gy in VMEM as the state walk
-    produces them. Returns per-row partials (BH, T) fp32, summed by the
+    produces them. Returns per-row partials (BH, T, 1) fp32, summed by the
     caller (ops.py). Same operand contract as ``wkv6_scan_mt_kernel`` plus
     gy: (BH, S, hd)."""
     BH, S, hd = r.shape
@@ -196,32 +205,31 @@ def wkv6_scan_mt_jvps_kernel(r, k, v, w, u, rds, kds, vds, wds, gy, uds=None,
                                n_t=T, has_ud=has_ud)
     seq_spec = pl.BlockSpec((1, block_s, hd), lambda b, s: (b, s, 0))
     seq_spec_t = pl.BlockSpec((T, 1, block_s, hd), lambda b, s: (0, b, s, 0))
-    in_specs = [seq_spec] * 4 + [
-        pl.BlockSpec((1, hd), lambda b, s: (b, 0)),
-    ] + [seq_spec_t] * 4
-    operands = [r, k, v, w, u, rds, kds, vds, wds]
+    in_specs = [seq_spec] * 4 + [_u_spec(hd)] + [seq_spec_t] * 4
+    operands = [r, k, v, w, _rows(u), rds, kds, vds, wds]
     if has_ud:
-        in_specs.append(pl.BlockSpec((T, 1, hd), lambda b, s: (0, b, 0)))
-        operands.append(uds)
+        in_specs.append(
+            pl.BlockSpec((T, 1, 1, hd), lambda b, s: (0, b, 0, 0)))
+        operands.append(_rows(uds))
     in_specs.append(seq_spec)                       # gy
     operands.append(gy)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, T), lambda b, s: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, T), jnp.float32),
+        out_specs=pl.BlockSpec((1, T, 1), lambda b, s: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((BH, T, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32),
                         pltpu.VMEM((T, hd, hd), jnp.float32),
                         pltpu.VMEM((T, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
 
 
 def wkv6_scan_mt_kernel(r, k, v, w, u, rds, kds, vds, wds, uds=None, *,
-                        block_s: int = 64, interpret=True,
+                        block_s: int = 64, interpret: bool,
                         emit_primal: bool = True):
     """Multi-tangent WKV recurrence: one pass over the primal r/k/v/w
     produces y plus all T ydots (same amortize-the-primal design as
@@ -246,13 +254,12 @@ def wkv6_scan_mt_kernel(r, k, v, w, u, rds, kds, vds, wds, uds=None, *,
                                has_ud=has_ud, emit_primal=emit_primal)
     seq_spec = pl.BlockSpec((1, block_s, hd), lambda b, s: (b, s, 0))
     seq_spec_t = pl.BlockSpec((T, 1, block_s, hd), lambda b, s: (0, b, s, 0))
-    in_specs = [seq_spec] * 4 + [
-        pl.BlockSpec((1, hd), lambda b, s: (b, 0)),
-    ] + [seq_spec_t] * 4
-    operands = [r, k, v, w, u, rds, kds, vds, wds]
+    in_specs = [seq_spec] * 4 + [_u_spec(hd)] + [seq_spec_t] * 4
+    operands = [r, k, v, w, _rows(u), rds, kds, vds, wds]
     if has_ud:
-        in_specs.append(pl.BlockSpec((T, 1, hd), lambda b, s: (0, b, 0)))
-        operands.append(uds)
+        in_specs.append(
+            pl.BlockSpec((T, 1, 1, hd), lambda b, s: (0, b, 0, 0)))
+        operands.append(_rows(uds))
     out_specs = [seq_spec_t]
     out_shape = [jax.ShapeDtypeStruct((T, BH, S, hd), jnp.float32)]
     if emit_primal:
@@ -266,7 +273,7 @@ def wkv6_scan_mt_kernel(r, k, v, w, u, rds, kds, vds, wds, uds=None, *,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32),
                         pltpu.VMEM((T, hd, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

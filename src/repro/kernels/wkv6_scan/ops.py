@@ -22,6 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import sum_partials
 from repro.kernels.wkv6_scan.kernel import (
     wkv6_scan_kernel,
     wkv6_scan_mt_jvps_kernel,
@@ -30,7 +31,7 @@ from repro.kernels.wkv6_scan.kernel import (
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
-def wkv6_scan(r, k, v, w, u, block_s: int = 64, interpret: bool = True):
+def wkv6_scan(r, k, v, w, u, block_s: int = 64, *, interpret: bool):
     """r,k,v,w: (B,S,H,hd); u: (H,hd). Returns y (B,S,H,hd) fp32.
 
     Fresh state per call (training semantics); the decode path keeps its
@@ -95,7 +96,7 @@ def _mt_layout(r, k, v, w, u, rds, kds, vds, wds, uds, block_s):
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def wkv6_scan_mt(r, k, v, w, u, rds, kds, vds, wds, uds=None,
-                 block_s: int = 64, interpret: bool = True):
+                 block_s: int = 64, *, interpret: bool):
     """Multi-tangent fused pass. r,k,v,w: (B,S,H,hd); u: (H,hd); tangents
     (T,B,S,H,hd) (+ uds (T,H,hd) or None). Returns (y, ydots) fp32."""
     ops, (B, S, H, hd, T, bs) = _mt_layout(r, k, v, w, u, rds, kds, vds, wds,
@@ -109,7 +110,7 @@ def wkv6_scan_mt(r, k, v, w, u, rds, kds, vds, wds, uds=None,
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def wkv6_scan_mt_tangents(r, k, v, w, u, rds, kds, vds, wds, uds=None,
-                          block_s: int = 64, interpret: bool = True):
+                          block_s: int = 64, *, interpret: bool):
     """Tangent-only fused pass -> ydots (T,B,S,H,hd). Same contract as
     ``wkv6_scan_mt`` but skips the primal y output (the primal state walk
     still runs in-kernel — the tangent recurrence needs S_{t-1})."""
@@ -123,7 +124,7 @@ def wkv6_scan_mt_tangents(r, k, v, w, u, rds, kds, vds, wds, uds=None,
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def wkv6_scan_mt_jvps(r, k, v, w, u, rds, kds, vds, wds, gy, uds=None,
-                      block_s: int = 64, interpret: bool = True):
+                      block_s: int = 64, *, interpret: bool):
     """Fused jvp-contraction epilogue -> jvps (T,) fp32 = <gy, ydot_t>.
 
     Same operand contract as ``wkv6_scan_mt`` plus the output cotangent
@@ -140,4 +141,4 @@ def wkv6_scan_mt_jvps(r, k, v, w, u, rds, kds, vds, wds, gy, uds=None,
     parts = wkv6_scan_mt_jvps_kernel(rb, kb, vb, wb, ub, rdb, kdb, vdb, wdb,
                                      gyb, udb, block_s=bs,
                                      interpret=interpret)
-    return parts.sum(axis=0)
+    return sum_partials(parts)

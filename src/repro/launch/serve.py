@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs import SpryConfig, get_config, reduce_config
 from repro.models import get_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.encdec import encode as encdec_encode
 from repro.peft import init_peft
 
@@ -155,7 +156,7 @@ def run_engine(cfg, n_requests, prompt_len, steps, max_batch=4,
                cache_capacity=4, telemetry=None, seed=0):
     """Drive the multi-tenant ServingEngine with ``n_requests`` requests on
     distinct synthetic adapters (the CLI/CI smoke path for the engine +
-    adapter cache + telemetry stack). Returns (outputs, engine)."""
+    adapter cache + telemetry stack). Returns (outputs, engine, requests)."""
     from repro.launch.adapter_cache import AdapterCache, SyntheticAdapterStore
     from repro.launch.serving import Request, ServingEngine
 
@@ -174,10 +175,11 @@ def run_engine(cfg, n_requests, prompt_len, steps, max_batch=4,
                     max_new_tokens=steps)
             for i in range(n_requests)]
     outputs = engine.run(reqs)
-    return outputs, engine
+    return outputs, engine, reqs
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="rwkv6-1.6b")
     ap.add_argument("--batch", type=int, default=4)
@@ -213,7 +215,7 @@ def main():
                       n_requests=args.engine, prompt_len=args.prompt_len,
                       steps=args.steps, max_batch=args.batch,
                       cache_capacity=args.cache_capacity)
-        outputs, engine = run_engine(
+        outputs, engine, _ = run_engine(
             cfg, args.engine, args.prompt_len, args.steps,
             max_batch=args.batch, cache_capacity=args.cache_capacity,
             telemetry=tel)

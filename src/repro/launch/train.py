@@ -38,6 +38,7 @@ warnings.filterwarnings("ignore",
 from repro.data import make_task
 from repro.data.loader import ClientDataset, stack_client_batches
 from repro.fl import dirichlet_partition, sample_clients
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import cls_logits, get_model
 from repro.models.common import accuracy_from_logits
 from repro.obs import NULL, MemoryProbe, make_telemetry
@@ -56,6 +57,25 @@ def personalized_accuracy(cfg, state, clients, x, y, rng, steps=5,
     from repro.core.forward_grad import forward_gradient
     from repro.models.registry import cls_loss
 
+    head_mask = {g: jax.tree.map(
+        lambda leaf: jnp.float32(1.0 if g == "head" else 0.0), t)
+        for g, t in state.peft.items()}
+
+    # jitted once per shape with the base as an argument: run op by op, a
+    # full-width model re-traces and recompiles its layer scan every step
+    @jax.jit
+    def head_step(base, peft, tokens, labels, key):
+        # head-only forward-gradient step (stays in the paper's paradigm)
+        batch = {"tokens": tokens, "labels": labels}
+        _, g, _ = forward_gradient(lambda p: cls_loss(cfg, base, p, batch),
+                                   peft, key, mask_tree=head_mask)
+        return jax.tree.map(lambda p_, g_: p_ - lr * g_, peft, g)
+
+    @jax.jit
+    def held_out_acc(base, peft, tokens, labels):
+        logits = cls_logits(cfg, base, peft, {"tokens": tokens})
+        return accuracy_from_logits(logits, labels)
+
     accs = []
     for c in clients[:max_clients]:
         idx = c.indices
@@ -66,20 +86,11 @@ def personalized_accuracy(cfg, state, clients, x, y, rng, steps=5,
         peft = state.peft
         for s in range(steps):
             take = rng.choice(tr, size=min(batch_size, len(tr)), replace=False)
-            batch = {"tokens": jnp.asarray(x[take]),
-                     "labels": jnp.asarray(y[take])}
-            # head-only forward-gradient step (stays in the paper's paradigm)
-            head_mask = {g: jax.tree.map(
-                lambda leaf: jnp.float32(1.0 if g == "head" else 0.0), t)
-                for g, t in peft.items()}
-            _, g, _ = forward_gradient(
-                lambda p: cls_loss(cfg, state.base, p, batch),
-                peft, jax.random.PRNGKey(int(take[0]) + s),
-                mask_tree=head_mask)
-            peft = jax.tree.map(lambda p_, g_: p_ - lr * g_, peft, g)
-        logits = cls_logits(cfg, state.base, peft,
-                            {"tokens": jnp.asarray(x[te])})
-        accs.append(float(accuracy_from_logits(logits, jnp.asarray(y[te]))))
+            peft = head_step(state.base, peft, jnp.asarray(x[take]),
+                             jnp.asarray(y[take]),
+                             jax.random.PRNGKey(int(take[0]) + s))
+        accs.append(float(held_out_acc(state.base, peft, jnp.asarray(x[te]),
+                                       jnp.asarray(y[te]))))
     return float(np.mean(accs)) if accs else float("nan")
 
 
@@ -99,6 +110,36 @@ def build_round_step(cfg, sc: SpryConfig, method: str, task="cls"):
     if method in ("fedmezo", "baffle", "fwdllm"):
         return make_zeroorder_round_step(cfg, sc, task, method=method), "zo"
     raise ValueError(method)
+
+
+def task_config(arch, task, seed, reduced):
+    """The model config with a classifier head sized to ``task``, and the
+    task's (x_train, y_train, x_test, y_test) arrays made from ``seed``."""
+    cfg = get_config(arch)
+    if reduced:
+        cfg = reduce_config(cfg)
+    data = make_task(task, seed=seed, vocab=cfg.vocab)
+    return dataclasses.replace(cfg, n_classes=int(data[1].max()) + 1), data
+
+
+def spry_config(method, local_lr=None, server_lr=None, **fields):
+    """``SpryConfig`` for ``method`` with its default client/server learning
+    rates and server optimizer; ``fields`` are the remaining SpryConfig
+    fields."""
+    defaults = {
+        "spry": (5e-3, 1e-2), "spry_periter": (5e-3, 1e-2),
+        "fedfgd": (5e-3, 1e-2),
+        "fedavg": (5e-2, 1.0), "fedyogi": (5e-2, 1e-2), "fedsgd": (5e-2, 1.0),
+        "fedavgsplit": (5e-2, 1.0),
+        "fedmezo": (5e-3, 1e-2), "baffle": (5e-3, 1e-2), "fwdllm": (5e-3, 1e-2),
+    }
+    d_lr, d_slr = defaults[method]
+    return SpryConfig(
+        local_lr=local_lr if local_lr is not None else d_lr,
+        server_lr=server_lr if server_lr is not None else d_slr,
+        server_opt="fedavg" if method in ("fedavg", "fedsgd", "fedavgsplit")
+        else "fedyogi",
+        **fields)
 
 
 def run_training(arch="roberta-large-lora", task="sst2", method="spry",
@@ -131,35 +172,14 @@ def run_training(arch="roberta-large-lora", task="sst2", method="spry",
             raise ValueError("--faults requires --runtime (the chaotic wire "
                              "lives in the federation engine)")
         wire_simulate = True
-    cfg = get_config(arch)
-    if reduced:
-        cfg = reduce_config(cfg)
-    x_tr, y_tr, x_te, y_te = make_task(task, seed=seed, vocab=cfg.vocab)
-    cfg = dataclasses.replace(cfg, n_classes=int(y_tr.max()) + 1)
-
-    defaults = {
-        "spry": (5e-3, 1e-2), "spry_periter": (5e-3, 1e-2),
-        "fedfgd": (5e-3, 1e-2),
-        "fedavg": (5e-2, 1.0), "fedyogi": (5e-2, 1e-2), "fedsgd": (5e-2, 1.0),
-        "fedavgsplit": (5e-2, 1.0),
-        "fedmezo": (5e-3, 1e-2), "baffle": (5e-3, 1e-2), "fwdllm": (5e-3, 1e-2),
-    }
-    d_lr, d_slr = defaults[method]
-    sc = SpryConfig(
-        n_clients_per_round=clients_per_round,
-        n_total_clients=total_clients,
-        local_iters=local_iters,
-        local_lr=local_lr if local_lr is not None else d_lr,
-        server_lr=server_lr if server_lr is not None else d_slr,
-        k_perturbations=k_perturbations,
-        jvp_clip=jvp_clip,
-        tangent_batch=tangent_batch,
-        fused_contraction=fused_contraction,
-        dirichlet_alpha=dirichlet_alpha,
-        server_opt="fedavg" if method in ("fedavg", "fedsgd", "fedavgsplit")
-        else "fedyogi",
-        seed=seed,
-    )
+    cfg, (x_tr, y_tr, x_te, y_te) = task_config(arch, task, seed, reduced)
+    sc = spry_config(
+        method, n_clients_per_round=clients_per_round,
+        n_total_clients=total_clients, local_iters=local_iters,
+        local_lr=local_lr, server_lr=server_lr,
+        k_perturbations=k_perturbations, jvp_clip=jvp_clip,
+        tangent_batch=tangent_batch, fused_contraction=fused_contraction,
+        dirichlet_alpha=dirichlet_alpha, seed=seed)
 
     route = estimator_route(sc)
     if tel.enabled:
@@ -396,6 +416,7 @@ def run_training(arch="roberta-large-lora", task="sst2", method="spry",
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="roberta-large-lora")
     ap.add_argument("--task", default="sst2")

@@ -68,6 +68,39 @@ def test_walker_finds_pallas_calls_through_nesting():
     assert "lora_dual" in kernel_src(calls[0])
 
 
+def _family_thunks():
+    from repro.kernels.lora_dual.ops import lora_dual_multi
+    from repro.kernels.mamba2_scan.ops import mamba2_scan
+    from repro.kernels.swa_attention.ops import swa_attention
+    from repro.kernels.wkv6_scan.ops import wkv6_scan
+    z = jnp.zeros
+    seq = z((1, 8, 2, 8))
+    return {
+        "lora_dual": ("_multi_kernel", lambda: lora_dual_multi(
+            z((2, 16)), z((2,), jnp.int32), z((16, 16)), z((2, 16, 1)),
+            z((2, 1, 16)), interpret=True)),
+        "wkv6_scan": ("_kernel", lambda: wkv6_scan(
+            seq, seq, seq, seq, z((2, 8)), interpret=True)),
+        "mamba2_scan": ("_kernel", lambda: mamba2_scan(
+            seq, z((1, 8, 4)), z((1, 8, 4)), z((1, 8, 2)), interpret=True)),
+        "swa_attention": ("_kernel", lambda: swa_attention(
+            z((1, 2, 16, 8)), z((1, 2, 16, 8)), z((1, 2, 16, 8)), window=8,
+            interpret=True)),
+    }
+
+
+@pytest.mark.parametrize("family", ["lora_dual", "wkv6_scan", "mamba2_scan",
+                                    "swa_attention"])
+def test_kernel_name_resolves(family):
+    """The walker names each kernel family's pallas_call by its kernel
+    function and source file (the family filters and VMEM rows key on
+    it)."""
+    name, thunk = _family_thunks()[family]
+    (call,) = pallas_calls(jax.make_jaxpr(thunk)())
+    assert kernel_name(call) == name
+    assert f"kernels/{family}/kernel.py" in kernel_src(call)
+
+
 # ---------------------------------------------------------------------------
 # rule 1: tangent-materialization
 # ---------------------------------------------------------------------------

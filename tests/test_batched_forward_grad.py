@@ -132,7 +132,7 @@ def test_lora_dual_mt_allclose(M, K, N, r, T):
     b = jax.random.normal(ks[5], (r, N)) * 0.05
     bd = jax.random.normal(ks[6], (T, r, N)) * 0.05
     y, yds = lora_dual_mt(x, xd, w, a, ad, b, bd, scale=2.0, block_m=64,
-                          block_n=64, block_k=64)
+                          block_n=64, block_k=64, interpret=True)
     yr, ydr = lora_dual_mt_ref(x, xd, w, a, ad, b, bd, 2.0)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-3,
                                rtol=1e-3)
@@ -155,7 +155,8 @@ def test_lora_dual_mt_odd_shapes_and_no_xdot(T):
     bd = jax.random.normal(ks[6], (T, r, N)) * 0.05
     for xdots in (xd, None):
         y, yds = lora_dual_mt(x, xdots, w, a, ad, b, bd, scale=1.5,
-                              block_m=64, block_n=64, block_k=64)
+                              block_m=64, block_n=64, block_k=64,
+                              interpret=True)
         yr, ydr = lora_dual_mt_ref(x, xdots, w, a, ad, b, bd, 1.5)
         np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-3,
                                    rtol=1e-3)
@@ -180,7 +181,7 @@ def test_lora_dual_mt_matches_columnwise_jvp():
         return x_ @ w + 2.0 * (x_ @ a_) @ b_
 
     y, yds = lora_dual_mt(x, xd, w, a, ad, b, bd, scale=2.0, block_m=64,
-                          block_n=64, block_k=64)
+                          block_n=64, block_k=64, interpret=True)
     for t in range(T):
         y_ref, yd_ref = jax.jvp(f, (x, a, b), (xd[t], ad[t], bd[t]))
         np.testing.assert_allclose(np.asarray(yds[t]), np.asarray(yd_ref),
@@ -203,7 +204,8 @@ def test_lora_dual_mt_jvps_fused_contraction(with_xdot):
     b = jax.random.normal(ks[5], (r, N)) * 0.05
     bd = jax.random.normal(ks[6], (T, r, N)) * 0.05
     gy = jax.random.normal(ks[7], (M, N))
-    jv = lora_dual_mt_jvps(x, w, a, ad, b, bd, gy, scale=2.0, xdots=xd)
+    jv = lora_dual_mt_jvps(x, w, a, ad, b, bd, gy, scale=2.0, xdots=xd,
+                           interpret=True)
     jvr = lora_dual_mt_jvps_ref(x, w, a, ad, b, bd, gy, 2.0, xdots=xd)
     np.testing.assert_allclose(np.asarray(jv), np.asarray(jvr), rtol=1e-4,
                                atol=1e-3)

@@ -107,13 +107,13 @@ def test_lora_jvps_kernel_matches_oracle(has_xd):
     (x, w, a, b), (xd, ad, bd), gy, scale = _lora_problem()
     xd = xd if has_xd else None
     jk = lora_dual_mt_jvps(x, w, a, ad, b, bd, gy, scale=scale, xdots=xd,
-                           impl="kernel")
+                           impl="kernel", interpret=True)
     jo = lora_dual_mt_jvps_ref(x, w, a, ad, b, bd, gy, scale, xdots=xd)
     np.testing.assert_allclose(np.asarray(jk), np.asarray(jo), rtol=2e-5,
                                atol=1e-6)
     # and against the reassociated jnp mirror (the dispatch 'jnp' route)
     jr = lora_dual_mt_jvps(x, w, a, ad, b, bd, gy, scale=scale, xdots=xd,
-                           impl="reassoc")
+                           impl="reassoc", interpret=True)
     np.testing.assert_allclose(np.asarray(jk), np.asarray(jr), rtol=2e-5,
                                atol=1e-6)
 
@@ -124,7 +124,8 @@ def test_lora_jvps_kernel_multiblock():
     (x, w, a, b), (xd, ad, bd), gy, scale = _lora_problem(
         M=200, K=130, N=70, r=4, T=3, seed=3)
     jk = lora_dual_mt_jvps(x, w, a, ad, b, bd, gy, scale=scale, xdots=xd,
-                           impl="kernel", block_m=64, block_n=64, block_k=64)
+                           impl="kernel", block_m=64, block_n=64, block_k=64,
+                           interpret=True)
     jo = lora_dual_mt_jvps_ref(x, w, a, ad, b, bd, gy, scale, xdots=xd)
     np.testing.assert_allclose(np.asarray(jk), np.asarray(jo), rtol=2e-5,
                                atol=1e-6)
@@ -137,10 +138,11 @@ def test_lora_jvps_stacked_bitwise_equals_single_tangent_passes():
     (x, w, a, b), (xd, ad, bd), gy, scale = _lora_problem()
     T = ad.shape[0]
     jk = lora_dual_mt_jvps(x, w, a, ad, b, bd, gy, scale=scale, xdots=xd,
-                           impl="kernel")
+                           impl="kernel", interpret=True)
     ones = jnp.concatenate([
         lora_dual_mt_jvps(x, w, a, ad[t:t + 1], b, bd[t:t + 1], gy,
-                          scale=scale, xdots=xd[t:t + 1], impl="kernel")
+                          scale=scale, xdots=xd[t:t + 1], impl="kernel",
+                          interpret=True)
         for t in range(T)])
     np.testing.assert_array_equal(np.asarray(jk), np.asarray(ones))
 
@@ -154,7 +156,7 @@ def test_wkv6_jvps_kernel_matches_oracle(with_ud, S):
     (r, k, v, w, u), (rd, kd, vd, wd, ud), gy = _wkv_problem(S=S)
     uds = ud if with_ud else None
     jk = wkv6_scan_mt_jvps(r, k, v, w, u, rd, kd, vd, wd, gy, uds,
-                           block_s=32)
+                           block_s=32, interpret=True)
     jo = wkv6_scan_mt_jvps_ref(r, k, v, w, u, rd, kd, vd, wd, gy, uds)
     np.testing.assert_allclose(np.asarray(jk), np.asarray(jo), rtol=2e-5,
                                atol=1e-5)
@@ -163,11 +165,12 @@ def test_wkv6_jvps_kernel_matches_oracle(with_ud, S):
 def test_wkv6_jvps_stacked_bitwise_equals_single_tangent_passes():
     (r, k, v, w, u), (rd, kd, vd, wd, ud), gy = _wkv_problem()
     T = rd.shape[0]
-    jk = wkv6_scan_mt_jvps(r, k, v, w, u, rd, kd, vd, wd, gy, ud, block_s=32)
+    jk = wkv6_scan_mt_jvps(r, k, v, w, u, rd, kd, vd, wd, gy, ud, block_s=32,
+                           interpret=True)
     ones = jnp.concatenate([
         wkv6_scan_mt_jvps(r, k, v, w, u, rd[t:t + 1], kd[t:t + 1],
                           vd[t:t + 1], wd[t:t + 1], gy, ud[t:t + 1],
-                          block_s=32)
+                          block_s=32, interpret=True)
         for t in range(T)])
     np.testing.assert_array_equal(np.asarray(jk), np.asarray(ones))
 
@@ -180,7 +183,7 @@ def test_wkv6_jvps_stacked_bitwise_equals_single_tangent_passes():
 def test_mamba2_jvps_kernel_matches_oracle(S):
     (xdt, bm, cm, dec), (xd, bd, cd, dd), gy = _mamba2_problem(S=S)
     jk = mamba2_scan_mt_jvps(xdt, bm, cm, dec, xd, bd, cd, dd, gy,
-                             block_s=32)
+                             block_s=32, interpret=True)
     jo = mamba2_scan_mt_jvps_ref(xdt, bm, cm, dec, xd, bd, cd, dd, gy)
     np.testing.assert_allclose(np.asarray(jk), np.asarray(jo), rtol=2e-5,
                                atol=1e-5)
@@ -190,10 +193,11 @@ def test_mamba2_jvps_stacked_bitwise_equals_single_tangent_passes():
     (xdt, bm, cm, dec), (xd, bd, cd, dd), gy = _mamba2_problem()
     T = xd.shape[0]
     jk = mamba2_scan_mt_jvps(xdt, bm, cm, dec, xd, bd, cd, dd, gy,
-                             block_s=32)
+                             block_s=32, interpret=True)
     ones = jnp.concatenate([
         mamba2_scan_mt_jvps(xdt, bm, cm, dec, xd[t:t + 1], bd[t:t + 1],
-                            cd[t:t + 1], dd[t:t + 1], gy, block_s=32)
+                            cd[t:t + 1], dd[t:t + 1], gy, block_s=32,
+                            interpret=True)
         for t in range(T)])
     np.testing.assert_array_equal(np.asarray(jk), np.asarray(ones))
 
@@ -222,7 +226,7 @@ def test_swa_jvps_kernel_matches_oracle(window, S, force_pad):
     (q, k, v), (qd, kd, vd), gy = _swa_problem(S=S)
     jk = swa_attention_mt_jvps(q, k, v, qd, kd, vd, gy, window=window,
                                block_q=64, block_k=64,
-                               force_pad_hd=force_pad)
+                               force_pad_hd=force_pad, interpret=True)
     jo = swa_attention_mt_jvps_ref(q, k, v, qd, kd, vd, gy, window=window)
     np.testing.assert_allclose(np.asarray(jk), np.asarray(jo), rtol=2e-4,
                                atol=1e-4)
@@ -232,11 +236,11 @@ def test_swa_jvps_stacked_bitwise_equals_single_tangent_passes():
     (q, k, v), (qd, kd, vd), gy = _swa_problem()
     T = qd.shape[0]
     jk = swa_attention_mt_jvps(q, k, v, qd, kd, vd, gy, window=48,
-                               block_q=64, block_k=64)
+                               block_q=64, block_k=64, interpret=True)
     ones = jnp.concatenate([
         swa_attention_mt_jvps(q, k, v, qd[t:t + 1], kd[t:t + 1],
                               vd[t:t + 1], gy, window=48, block_q=64,
-                              block_k=64)
+                              block_k=64, interpret=True)
         for t in range(T)])
     np.testing.assert_array_equal(np.asarray(jk), np.asarray(ones))
 
@@ -307,8 +311,8 @@ def test_vmap_of_contract_traces_jvps_epilogue(kind):
     calls = pallas_calls(jaxpr)
     assert len(calls) == 1, f"expected ONE _jvps pallas_call, got {calls}"
     (out_aval,) = [v.aval for v in calls[0].outvars]
-    # per-block partials: trailing tangent axis K, tiny total size
-    assert out_aval.shape[-1] == K
+    # per-block partials: trailing (tangent, 1) axes, tiny total size
+    assert out_aval.shape[-2:] == (K, 1)
     assert_no_tangent_stack(jaxpr, K, y_shape)
 
 

@@ -25,7 +25,7 @@ def test_lora_dual_allclose(M, K, N, r, dtype, atol):
     w = mk(ks[2], (K, N))
     a, ad = mk(ks[3], (K, r)), mk(ks[4], (K, r))
     b, bd = mk(ks[5], (r, N)), mk(ks[6], (r, N))
-    y, yd = lora_dual(x, xd, w, a, ad, b, bd, scale=2.0)
+    y, yd = lora_dual(x, xd, w, a, ad, b, bd, scale=2.0, interpret=True)
     yr, ydr = lora_dual_ref(x, xd, w, a, ad, b, bd, 2.0)
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(yr, np.float32), atol=atol, rtol=atol)
@@ -50,7 +50,7 @@ def test_lora_dual_matches_jax_jvp():
         return x_ @ w + 2.0 * (x_ @ a_) @ b_
 
     y_ref, yd_ref = jax.jvp(f, (x, a, b), (xd, ad, bd))
-    y, yd = lora_dual(x, xd, w, a, ad, b, bd, scale=2.0)
+    y, yd = lora_dual(x, xd, w, a, ad, b, bd, scale=2.0, interpret=True)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=1e-4,
                                rtol=1e-4)
     np.testing.assert_allclose(np.asarray(yd), np.asarray(yd_ref), atol=1e-4,
@@ -73,7 +73,7 @@ def test_lora_dual_odd_shapes(M, K, N, r):
     b = jax.random.normal(ks[5], (r, N)) * 0.05
     bd = jax.random.normal(ks[6], (r, N)) * 0.05
     y, yd = lora_dual(x, xd, w, a, ad, b, bd, scale=1.0, block_m=64,
-                      block_n=64, block_k=64)
+                      block_n=64, block_k=64, interpret=True)
     yr, ydr = lora_dual_ref(x, xd, w, a, ad, b, bd, 1.0)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-3,
                                rtol=1e-3)
@@ -98,7 +98,8 @@ def test_swa_attention_allclose(B, H, KV, S, hd, W, bq, bk):
     q = jax.random.normal(ks[0], (B, H, S, hd))
     k = jax.random.normal(ks[1], (B, KV, S, hd))
     v = jax.random.normal(ks[2], (B, KV, S, hd))
-    out = swa_attention(q, k, v, window=W, block_q=bq, block_k=bk)
+    out = swa_attention(q, k, v, window=W, block_q=bq, block_k=bk,
+                        interpret=True)
     kr = jnp.repeat(k, H // KV, axis=1)
     vr = jnp.repeat(v, H // KV, axis=1)
     ref = swa_attention_ref(q, kr, vr, window=W)
@@ -112,7 +113,8 @@ def test_swa_attention_bf16():
     q = jax.random.normal(ks[0], (B, H, S, hd)).astype(jnp.bfloat16)
     k = jax.random.normal(ks[1], (B, H, S, hd)).astype(jnp.bfloat16)
     v = jax.random.normal(ks[2], (B, H, S, hd)).astype(jnp.bfloat16)
-    out = swa_attention(q, k, v, window=64, block_q=64, block_k=64)
+    out = swa_attention(q, k, v, window=64, block_q=64, block_k=64,
+                        interpret=True)
     ref = swa_attention_ref(q, k, v, window=64)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=3e-2,
@@ -128,7 +130,8 @@ def test_swa_attention_window_sweep(wmul):
     q = jax.random.normal(ks[0], (1, 2, 384, 32))
     k = jax.random.normal(ks[1], (1, 2, 384, 32))
     v = jax.random.normal(ks[2], (1, 2, 384, 32))
-    out = swa_attention(q, k, v, window=W, block_q=96, block_k=96)
+    out = swa_attention(q, k, v, window=W, block_q=96, block_k=96,
+                        interpret=True)
     ref = swa_attention_ref(q, k, v, window=W)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3,
                                rtol=2e-3)
@@ -149,7 +152,7 @@ def test_wkv6_scan_allclose(B, S, H, hd, bs):
     v = jax.random.normal(ks[2], (B, S, H, hd)) * 0.5
     w = jax.nn.sigmoid(jax.random.normal(ks[3], (B, S, H, hd)))
     u = jax.random.normal(ks[4], (H, hd)) * 0.5
-    y = wkv6_scan(r, k, v, w, u, block_s=bs)
+    y = wkv6_scan(r, k, v, w, u, block_s=bs, interpret=True)
     yr, _ = wkv6_scan_ref(r, k, v, w, u)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-4,
                                rtol=1e-4)
@@ -163,7 +166,7 @@ def test_wkv6_matches_model_recurrence():
     r, k, v = (jax.random.normal(ks[i], (B, S, H, hd)) * 0.3 for i in range(3))
     w = jax.nn.sigmoid(jax.random.normal(ks[3], (B, S, H, hd)))
     u = jax.random.normal(ks[4], (H, hd)) * 0.3
-    y_kernel = wkv6_scan(r, k, v, w, u, block_s=16)
+    y_kernel = wkv6_scan(r, k, v, w, u, block_s=16, interpret=True)
     y_model, _ = wkv6_recurrence(r, k, v, w, u,
                                  jnp.zeros((B, H, hd, hd), jnp.float32))
     np.testing.assert_allclose(np.asarray(y_kernel), np.asarray(y_model),

@@ -40,7 +40,7 @@ def _problem(B=2, S=96, H=3, hd=8, N=16, T=3, seed=0):
 @pytest.mark.parametrize("S", [96, 75])
 def test_mamba2_primal_kernel_matches_ref(S):
     (xdt, bm, cm, dec), _ = _problem(S=S)
-    y = mamba2_scan(xdt, bm, cm, dec, block_s=32)
+    y = mamba2_scan(xdt, bm, cm, dec, block_s=32, interpret=True)
     yr, _ = mamba2_scan_ref(xdt, bm, cm, dec)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-5,
                                rtol=1e-5)
@@ -49,7 +49,8 @@ def test_mamba2_primal_kernel_matches_ref(S):
 @pytest.mark.parametrize("S", [96, 75])
 def test_mamba2_mt_matches_jvp_oracle(S):
     (xdt, bm, cm, dec), (xd, bd, cd, dd) = _problem(S=S)
-    y, yds = mamba2_scan_mt(xdt, bm, cm, dec, xd, bd, cd, dd, block_s=32)
+    y, yds = mamba2_scan_mt(xdt, bm, cm, dec, xd, bd, cd, dd, block_s=32,
+                            interpret=True)
     yr, ydr = mamba2_scan_mt_ref(xdt, bm, cm, dec, xd, bd, cd, dd)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-5,
                                rtol=1e-5)
@@ -61,19 +62,20 @@ def test_mamba2_mt_stacked_bitwise_equals_single_tangent_passes():
     (xdt, bm, cm, dec), (xd, bd, cd, dd) = _problem()
     T = xd.shape[0]
     yds = mamba2_scan_mt_tangents(xdt, bm, cm, dec, xd, bd, cd, dd,
-                                  block_s=32)
+                                  block_s=32, interpret=True)
     for t in range(T):
         one = mamba2_scan_mt_tangents(xdt, bm, cm, dec, xd[t:t + 1],
                                       bd[t:t + 1], cd[t:t + 1], dd[t:t + 1],
-                                      block_s=32)
+                                      block_s=32, interpret=True)
         np.testing.assert_array_equal(np.asarray(yds[t]), np.asarray(one[0]))
 
 
 def test_mamba2_mt_tangents_match_full_pass():
     (xdt, bm, cm, dec), (xd, bd, cd, dd) = _problem(seed=5)
-    _, yds = mamba2_scan_mt(xdt, bm, cm, dec, xd, bd, cd, dd, block_s=32)
+    _, yds = mamba2_scan_mt(xdt, bm, cm, dec, xd, bd, cd, dd, block_s=32,
+                            interpret=True)
     ydt = mamba2_scan_mt_tangents(xdt, bm, cm, dec, xd, bd, cd, dd,
-                                  block_s=32)
+                                  block_s=32, interpret=True)
     np.testing.assert_array_equal(np.asarray(yds), np.asarray(ydt))
 
 
@@ -84,7 +86,8 @@ def test_mamba2_bc_streams_not_widened_per_head():
     B, S, H, hd, N = 1, 64, 4, 8, 16
     (xdt, bm, cm, dec), _ = _problem(B=B, S=S, H=H, hd=hd, N=N)
     jaxpr = jax.make_jaxpr(
-        lambda *a: mamba2_scan(*a, block_s=32))(xdt, bm, cm, dec)
+        lambda *a: mamba2_scan(*a, block_s=32,
+                               interpret=True))(xdt, bm, cm, dec)
 
     def walk(j):
         for eqn in j.eqns:

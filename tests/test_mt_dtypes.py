@@ -67,7 +67,7 @@ def test_lora_mt_fp32_accumulator_bitwise(dt):
     BITWISE the fp32-upcast oracle rounded once to the operand dtype —
     i.e. no intermediate rounding anywhere in the K-reduction."""
     x, w, a, b, ad, bd, xd, _ = _lora_problem(dt)
-    y, yds = lora_dual_mt(x, xd, w, a, ad, b, bd)
+    y, yds = lora_dual_mt(x, xd, w, a, ad, b, bd, interpret=True)
     assert y.dtype == dt and yds.dtype == dt
     yr, ydr = lora_dual_mt_ref(x.astype(jnp.float32),
                                xd.astype(jnp.float32),
@@ -82,7 +82,8 @@ def test_lora_jvps_fp32_out_vs_fp32_oracle(dt):
     """The epilogue's jvp partials stay fp32 for low-precision operands and
     match the fp32-upcast oracle at fp32-reduction tolerance."""
     x, w, a, b, ad, bd, xd, gy = _lora_problem(dt)
-    jk = lora_dual_mt_jvps(x, w, a, ad, b, bd, gy, xdots=xd, impl="kernel")
+    jk = lora_dual_mt_jvps(x, w, a, ad, b, bd, gy, xdots=xd, impl="kernel",
+                           interpret=True)
     assert jk.dtype == jnp.float32
     jr = lora_dual_mt_jvps_ref(x.astype(jnp.float32),
                                w.astype(jnp.float32), a, ad, b, bd, gy,
@@ -111,7 +112,8 @@ def test_wkv6_mt_low_precision_operands(dt):
     must match the oracle on the SAME upcast inputs bitwise-rounded-once:
     the state walk itself is pure fp32."""
     (r, k, v, w, u), (rd, kd, vd, wd, ud), _ = _wkv_problem(dt)
-    y, yds = wkv6_scan_mt(r, k, v, w, u, rd, kd, vd, wd, ud, block_s=32)
+    y, yds = wkv6_scan_mt(r, k, v, w, u, rd, kd, vd, wd, ud, block_s=32,
+                          interpret=True)
     assert y.dtype == jnp.float32
     yr, ydr = wkv6_scan_mt_ref(*_f32((r, k, v, w, u)),
                                *_f32((rd, kd, vd, wd, ud)))
@@ -125,7 +127,7 @@ def test_wkv6_mt_low_precision_operands(dt):
 def test_wkv6_jvps_low_precision_operands(dt):
     (r, k, v, w, u), (rd, kd, vd, wd, ud), gy = _wkv_problem(dt)
     jk = wkv6_scan_mt_jvps(r, k, v, w, u, rd, kd, vd, wd, gy, ud,
-                           block_s=32)
+                           block_s=32, interpret=True)
     assert jk.dtype == jnp.float32
     jr = wkv6_scan_mt_jvps_ref(*_f32((r, k, v, w, u)),
                                *_f32((rd, kd, vd, wd)), gy,
@@ -153,7 +155,7 @@ def test_swa_mt_low_precision_operands(dt):
     fp32-upcast oracle."""
     (q, k, v), (qd, kd, vd), _ = _swa_problem(dt)
     out, outds = swa_attention_mt(q, k, v, qd, kd, vd, window=32,
-                                  block_q=32, block_k=32)
+                                  block_q=32, block_k=32, interpret=True)
     assert out.dtype == dt
     outr, outdr = swa_attention_mt_ref(*_f32((q, k, v)),
                                        *_f32((qd, kd, vd)), window=32)
@@ -168,7 +170,7 @@ def test_swa_mt_low_precision_operands(dt):
 def test_swa_jvps_low_precision_operands(dt):
     (q, k, v), (qd, kd, vd), gy = _swa_problem(dt)
     jk = swa_attention_mt_jvps(q, k, v, qd, kd, vd, gy, window=32,
-                               block_q=32, block_k=32)
+                               block_q=32, block_k=32, interpret=True)
     assert jk.dtype == jnp.float32
     jr = swa_attention_mt_jvps_ref(*_f32((q, k, v)), *_f32((qd, kd, vd)),
                                    gy, window=32)

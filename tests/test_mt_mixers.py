@@ -61,7 +61,8 @@ def _swa_problem(B=1, H=4, KV=2, S=128, hd=32, T=3, seed=1):
 def test_wkv6_mt_matches_jvp_oracle(with_ud):
     (r, k, v, w, u), (rd, kd, vd, wd, ud) = _wkv_problem()
     uds = ud if with_ud else None
-    y, yds = wkv6_scan_mt(r, k, v, w, u, rd, kd, vd, wd, uds, block_s=32)
+    y, yds = wkv6_scan_mt(r, k, v, w, u, rd, kd, vd, wd, uds, block_s=32,
+                          interpret=True)
     yr, ydr = wkv6_scan_mt_ref(r, k, v, w, u, rd, kd, vd, wd, uds)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-5,
                                rtol=1e-5)
@@ -73,7 +74,8 @@ def test_wkv6_mt_odd_seq_padding():
     """Non-block-multiple S exercises the padded-step state preservation
     (w=1 keeps S, wd=0/kvd=0 keep every Sd)."""
     (r, k, v, w, u), (rd, kd, vd, wd, ud) = _wkv_problem(S=75)
-    y, yds = wkv6_scan_mt(r, k, v, w, u, rd, kd, vd, wd, ud, block_s=32)
+    y, yds = wkv6_scan_mt(r, k, v, w, u, rd, kd, vd, wd, ud, block_s=32,
+                          interpret=True)
     yr, ydr = wkv6_scan_mt_ref(r, k, v, w, u, rd, kd, vd, wd, ud)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-5,
                                rtol=1e-5)
@@ -87,18 +89,21 @@ def test_wkv6_mt_stacked_bitwise_equals_single_tangent_passes():
     scratch) — the batched estimate is exactly K column-by-column jvps."""
     (r, k, v, w, u), (rd, kd, vd, wd, ud) = _wkv_problem()
     T = rd.shape[0]
-    yds = wkv6_scan_mt_tangents(r, k, v, w, u, rd, kd, vd, wd, ud, block_s=32)
+    yds = wkv6_scan_mt_tangents(r, k, v, w, u, rd, kd, vd, wd, ud, block_s=32,
+                                interpret=True)
     for t in range(T):
         one = wkv6_scan_mt_tangents(r, k, v, w, u, rd[t:t + 1], kd[t:t + 1],
                                     vd[t:t + 1], wd[t:t + 1], ud[t:t + 1],
-                                    block_s=32)
+                                    block_s=32, interpret=True)
         np.testing.assert_array_equal(np.asarray(yds[t]), np.asarray(one[0]))
 
 
 def test_wkv6_mt_tangents_match_full_pass():
     (r, k, v, w, u), (rd, kd, vd, wd, ud) = _wkv_problem(seed=5)
-    _, yds = wkv6_scan_mt(r, k, v, w, u, rd, kd, vd, wd, ud, block_s=32)
-    ydt = wkv6_scan_mt_tangents(r, k, v, w, u, rd, kd, vd, wd, ud, block_s=32)
+    _, yds = wkv6_scan_mt(r, k, v, w, u, rd, kd, vd, wd, ud, block_s=32,
+                          interpret=True)
+    ydt = wkv6_scan_mt_tangents(r, k, v, w, u, rd, kd, vd, wd, ud, block_s=32,
+                                interpret=True)
     np.testing.assert_array_equal(np.asarray(yds), np.asarray(ydt))
 
 
@@ -110,7 +115,7 @@ def test_wkv6_mt_tangents_match_full_pass():
 def test_swa_mt_matches_jvp_oracle(window):
     (q, k, v), (qd, kd, vd) = _swa_problem()
     out, outds = swa_attention_mt(q, k, v, qd, kd, vd, window=window,
-                                  block_q=64, block_k=64)
+                                  block_q=64, block_k=64, interpret=True)
     outr, outdr = swa_attention_mt_ref(q, k, v, qd, kd, vd, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(outr), atol=2e-3,
                                rtol=2e-3)
@@ -123,13 +128,14 @@ def test_swa_mt_odd_seq_padding():
     keys sit beyond every real query's causal band)."""
     (q, k, v), (qd, kd, vd) = _swa_problem(S=100, seed=7)
     out, outds = swa_attention_mt(q, k, v, qd, kd, vd, window=48, block_q=64,
-                                  block_k=64)
+                                  block_k=64, interpret=True)
     outr, outdr = swa_attention_mt_ref(q, k, v, qd, kd, vd, window=48)
     np.testing.assert_allclose(np.asarray(out), np.asarray(outr), atol=2e-3,
                                rtol=2e-3)
     np.testing.assert_allclose(np.asarray(outds), np.asarray(outdr),
                                atol=2e-3, rtol=2e-3)
-    out2 = swa_attention(q, k, v, window=48, block_q=64, block_k=64)
+    out2 = swa_attention(q, k, v, window=48, block_q=64, block_k=64,
+                         interpret=True)
     np.testing.assert_allclose(np.asarray(out2), np.asarray(outr), atol=2e-3,
                                rtol=2e-3)
 
@@ -144,7 +150,8 @@ def test_swa_mixed_blocks_no_padding_explosion():
     q = jax.random.normal(ks[0], (1, 2, 100, 32))
     k = jax.random.normal(ks[1], (1, 2, 100, 32))
     v = jax.random.normal(ks[2], (1, 2, 100, 32))
-    out = swa_attention(q, k, v, window=48, block_q=128, block_k=64)
+    out = swa_attention(q, k, v, window=48, block_q=128, block_k=64,
+                        interpret=True)
     ref = swa_attention_ref(q, k, v, window=48)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3,
                                rtol=2e-3)
@@ -154,11 +161,11 @@ def test_swa_mt_stacked_bitwise_equals_single_tangent_passes():
     (q, k, v), (qd, kd, vd) = _swa_problem()
     T = qd.shape[0]
     outds = swa_attention_mt_tangents(q, k, v, qd, kd, vd, window=48,
-                                      block_q=64, block_k=64)
+                                      block_q=64, block_k=64, interpret=True)
     for t in range(T):
         one = swa_attention_mt_tangents(q, k, v, qd[t:t + 1], kd[t:t + 1],
                                         vd[t:t + 1], window=48, block_q=64,
-                                        block_k=64)
+                                        block_k=64, interpret=True)
         np.testing.assert_array_equal(np.asarray(outds[t]),
                                       np.asarray(one[0]))
 
@@ -177,7 +184,8 @@ def test_swa_gqa_parity_with_model_convention(H, KV):
     q = jax.random.normal(ks[0], (B, H, S, hd))
     k = jax.random.normal(ks[1], (B, KV, S, hd))
     v = jax.random.normal(ks[2], (B, KV, S, hd))
-    out = swa_attention(q, k, v, window=W, block_q=64, block_k=64)
+    out = swa_attention(q, k, v, window=W, block_q=64, block_k=64,
+                        interpret=True)
     rep = H // KV
     ref = swa_attention_ref(q, jnp.repeat(k, rep, axis=1),
                             jnp.repeat(v, rep, axis=1), window=W)
@@ -198,7 +206,7 @@ def test_swa_kernel_path_has_no_repeat():
     v = jnp.zeros((B, KV, S, hd))
     jaxpr = jax.make_jaxpr(
         lambda q_, k_, v_: swa_attention(q_, k_, v_, window=48, block_q=64,
-                                         block_k=64))(q, k, v)
+                                         block_k=64, interpret=True))(q, k, v)
 
     def walk(j):
         for eqn in j.eqns:
@@ -231,14 +239,15 @@ def test_swa_forced_pad_hd_under_interpret(hd):
     k = jax.random.normal(ks[1], (B, H, S, hd))
     v = jax.random.normal(ks[2], (B, H, S, hd))
     out = swa_attention(q, k, v, window=W, block_q=64, block_k=64,
-                        force_pad_hd=True)
+                        force_pad_hd=True, interpret=True)
     ref = swa_attention_ref(q, k, v, window=W)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3,
                                rtol=2e-3)
     # the pad must actually be live: the kernel input gains padded lanes
     jaxpr = jax.make_jaxpr(
         lambda q_, k_, v_: swa_attention(q_, k_, v_, window=W, block_q=64,
-                                         block_k=64, force_pad_hd=True))(
+                                         block_k=64, force_pad_hd=True,
+                                         interpret=True))(
         q, k, v)
     assert f"{128 * ((hd + 127) // 128)}" in str(jaxpr)
 
@@ -246,7 +255,8 @@ def test_swa_forced_pad_hd_under_interpret(hd):
 def test_swa_mt_forced_pad_hd():
     (q, k, v), (qd, kd, vd) = _swa_problem(hd=48)
     out, outds = swa_attention_mt(q, k, v, qd, kd, vd, window=48, block_q=64,
-                                  block_k=64, force_pad_hd=True)
+                                  block_k=64, force_pad_hd=True,
+                                  interpret=True)
     outr, outdr = swa_attention_mt_ref(q, k, v, qd, kd, vd, window=48)
     np.testing.assert_allclose(np.asarray(out), np.asarray(outr), atol=2e-3,
                                rtol=2e-3)
