@@ -55,6 +55,19 @@ layer's ``*_jvp_contract`` ops, whose custom-vmap lowering picks the
 site are contracted blockwise in VMEM and NEVER written to HBM. The jvp
 scalars equal the standard route's up to float reassociation of the
 contraction.
+
+Phase scopes
+------------
+The estimator's work is named in the compiled program's HLO metadata
+(``jax.named_scope``): ``spry.primal`` is the once-per-estimate linearize
+(on the fused route also the site primal, the post-head vjp and ``zw``);
+``spry.tangents`` is the evaluation of the K tangents through the linear
+map. The sequential and K=1 routes run primal and tangent in one
+``jax.jvp``, which is scoped ``spry.tangents`` whole. The linear map from
+``jax.linearize`` replays, inside the scope where it is applied, the name
+stack it was built under, so its ops read ``spry.tangents/...spry.primal``:
+an op under ``spry.tangents`` is tangent work whatever scope follows. The
+scopes change metadata only, never the compiled buffers or numbers.
 """
 from __future__ import annotations
 
@@ -94,6 +107,12 @@ def _combine(jvps, vs, k_total):
     reconstruction so the two are bit-identical (same ops, same inputs)."""
     return jax.tree.map(
         lambda v: jnp.tensordot(jvps, v, axes=[[0], [0]]) / k_total, vs)
+
+
+def _tangents(tangent_map, vs):
+    """The stacked tangents ``vs`` through the linear map, one vmap."""
+    with jax.named_scope("spry.tangents"):
+        return jax.vmap(tangent_map)(vs)
 
 
 # ---------------------------------------------------------------------------
@@ -171,6 +190,7 @@ def _tree_vdot(g, t):
     return sum(leaves) if leaves else jnp.float32(0.0)
 
 
+@jax.named_scope("spry.primal")
 def fused_linearize(loss_fn: SplitLoss, peft32):
     """(loss, jvp_of) for the fused-contraction route.
 
@@ -291,7 +311,7 @@ def forward_gradient(loss_fn, peft, key, k_perturbations=1, mask_tree=None,
     if K == 1 and not fused:
         # no tangent stacking needed — single dual-number pass
         v = masked_perturbation(jax.random.fold_in(key, 0), peft32, mask_tree)
-        with forward_ad_region():
+        with forward_ad_region(), jax.named_scope("spry.tangents"):
             loss, jvp = jax.jvp(loss_fn, (peft32,), (v,))
         jvps = clip(jnp.reshape(jvp, (1,)))
         vs = jax.tree.map(lambda x: x[None], v)
@@ -305,7 +325,7 @@ def forward_gradient(loss_fn, peft, key, k_perturbations=1, mask_tree=None,
             g, jvps, loss_acc = carry
             ki = jax.random.fold_in(key, i)
             v = masked_perturbation(ki, peft32, mask_tree)
-            with forward_ad_region():
+            with forward_ad_region(), jax.named_scope("spry.tangents"):
                 loss, jvp = jax.jvp(loss_fn, (peft32,), (v,))
             if jvp_clip is not None:
                 jvp = jnp.clip(jvp, -jvp_clip, jvp_clip)
@@ -328,12 +348,12 @@ def forward_gradient(loss_fn, peft, key, k_perturbations=1, mask_tree=None,
     if fused:
         loss, tangent_map = fused_linearize(loss_fn, peft32)
     else:
-        with forward_ad_region():
+        with forward_ad_region(), jax.named_scope("spry.primal"):
             loss, tangent_map = jax.linearize(loss_fn, peft32)
 
     if tb >= K:
         vs = stacked_perturbations(key, peft32, jnp.arange(K), mask_tree)
-        jvps = clip(jax.vmap(tangent_map)(vs))
+        jvps = clip(_tangents(tangent_map, vs))
         return loss, _combine(jvps, vs, K), jvps
 
     # chunked: ceil(K/tb) groups of tb tangents, scanned sequentially
@@ -352,7 +372,7 @@ def forward_gradient(loss_fn, peft, key, k_perturbations=1, mask_tree=None,
         idx = start + jnp.arange(tb)
         vs_g = stacked_perturbations(key, peft32, idx, mask_tree)
         live = (idx < K).astype(jnp.float32)
-        jvps_g = clip(jax.vmap(tangent_map)(vs_g)) * live
+        jvps_g = clip(_tangents(tangent_map, vs_g)) * live
         g_acc = jax.tree.map(jnp.add, g_acc, _combine(jvps_g, vs_g, K))
         jvps_acc = jax.lax.dynamic_update_slice(jvps_acc, jvps_g, (start,))
         return (g_acc, jvps_acc), None

@@ -32,6 +32,16 @@ device-parallel cohort executor instead of one in-process vmap:
 these pieces; the runtime's ideal path (full participation, no wire
 quantization, whole-cohort executor) is bit-identical by construction —
 asserted in tests/test_runtime.py.
+
+Each piece runs under a ``jax.named_scope`` that names its phase in the
+compiled program's HLO metadata: ``spry.client_update`` (the client fns:
+mask trees, keys, perturbations, the jvp·v combine, the local SGD step and
+the delta), ``spry.server`` (the round steps' own work — assignment, round
+key, metrics — and the rebuild, aggregation and server optimizer). The
+estimator inside a client fn names its own ``spry.primal`` and
+``spry.tangents`` (core/forward_grad.py). Scopes nest: the last ``spry.``
+component of an op's ``op_name`` names its phase, save that an op under
+``spry.tangents`` is always tangent work (bench/phases.py).
 """
 from __future__ import annotations
 
@@ -122,6 +132,7 @@ def make_client_update_fn(cfg, spry_cfg, task: str = "cls"):
     K = spry_cfg.k_perturbations
     lr_l = spry_cfg.local_lr
 
+    @jax.named_scope("spry.client_update")
     def client_update(base, peft, round_key, seed_id, mask_row, client_batch):
         index = enumerate_units(peft)
         mask_tree = build_mask_tree(peft, index, mask_row)
@@ -193,6 +204,7 @@ def make_client_jvp_fn(cfg, spry_cfg, task: str = "cls"):
     """
     K = spry_cfg.k_perturbations
 
+    @jax.named_scope("spry.client_update")
     def client_jvp(base, peft, round_key, seed_id, mask_row, client_batch):
         index = enumerate_units(peft)
         mask_tree = build_mask_tree(peft, index, mask_row)
@@ -217,6 +229,7 @@ def make_rebuild_fn():
 
     Returns ``rebuild(peft, round_key, seed_id, mask_row, jvps) -> grad``.
     """
+    @jax.named_scope("spry.server")
     def rebuild(peft, round_key, seed_id, mask_row, jvps):
         index = enumerate_units(peft)
         mask_tree = build_mask_tree(peft, index, mask_row)
@@ -242,6 +255,7 @@ def make_count_tree(peft, index, counts, head_count):
     }
 
 
+@jax.named_scope("spry.server")
 def aggregate_payloads(peft, index, stacked, counts, head_count):
     """Weighted-union average of stacked per-client payload trees.
 
@@ -266,6 +280,7 @@ def make_round_step(cfg, spry_cfg, task: str = "cls", split: bool = True):
     M = spry_cfg.n_clients_per_round
     client_update = make_client_update_fn(cfg, spry_cfg, task)
 
+    @jax.named_scope("spry.server")
     def round_step(state: SpryState, batch):
         base, peft = state.base, state.peft
         index = enumerate_units(peft)
@@ -314,6 +329,7 @@ def make_round_step_per_iteration(cfg, spry_cfg, task: str = "cls"):
     client_jvp = make_client_jvp_fn(cfg, spry_cfg, task)
     rebuild = make_rebuild_fn()
 
+    @jax.named_scope("spry.server")
     def round_step(state: SpryState, batch):
         base, peft = state.base, state.peft
         index = enumerate_units(peft)

@@ -2,7 +2,9 @@
 
 The server treats the aggregated client delta  Δ = w' − w  as a pseudo-
 gradient and applies FedAvg / FedSGD / FedAdam / FedYogi / FedAdagrad.
-All are pure pytree functions so they compose into the jitted round step.
+All are pure pytree functions so they compose into the jitted round step,
+where ``server_update`` names its work ``spry.server`` (the round step's
+server phase, core/spry.py).
 """
 from __future__ import annotations
 
@@ -25,6 +27,7 @@ def server_init(params) -> ServerState:
                        tree_zeros_like(params))
 
 
+@jax.named_scope("spry.server")
 def server_update(kind: str, params, delta, state: ServerState, lr: float,
                   b1: float = 0.9, b2: float = 0.99, tau: float = 1e-3):
     """Apply one server-optimizer step. ``delta`` is the aggregated client

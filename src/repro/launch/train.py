@@ -11,6 +11,7 @@ with periodic generalized/personalized evaluation.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import json
 import time
@@ -41,7 +42,7 @@ from repro.fl import dirichlet_partition, sample_clients
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import cls_logits, get_model
 from repro.models.common import accuracy_from_logits
-from repro.obs import NULL, MemoryProbe, make_telemetry
+from repro.obs import NULL, MemoryProbe, compile_stats, make_telemetry
 from repro.peft import init_peft
 
 
@@ -154,7 +155,17 @@ def run_training(arch="roberta-large-lora", task="sst2", method="spry",
                  telemetry=None, faults=None, quorum=None,
                  checkpoint_dir=None, checkpoint_every=1, resume=False,
                  async_mode=False, buffer_size=4, staleness_decay=0.5,
-                 async_concurrency=None, max_staleness=None):
+                 async_concurrency=None, max_staleness=None, profile_dir=None):
+    """Run ``rounds`` federated rounds; returns the eval history.
+
+    With ``telemetry`` on, the in-process path spans each round's
+    ``train.prep`` (client draw, batch stacking, host-to-device copy),
+    ``train.dispatch`` (the jitted step's call, which returns before the
+    device finishes) and ``train.loss_read`` (the loss read, which waits for
+    it), and its ``round`` event carries ``compiles`` where the round
+    compiled. ``profile_dir`` records a profiler trace of the round loop
+    there, with those spans on the host line beside the device operations.
+    """
     tel = telemetry if telemetry is not None else NULL
     if async_mode:
         # the async engine IS a runtime path (population + wire frames)
@@ -329,83 +340,95 @@ def run_training(arch="roberta-large-lora", task="sst2", method="spry",
             log(f"[{method}] personalized_acc="
                 f"{history[-1]['personalized_acc']:.4f}")
         return history
-    for r in range(start_round, rounds):
-        t_round = time.perf_counter()
-        if engine is not None and async_mode:
-            state, metrics, report = engine.run_version(state, batch_size)
-            # async reports carry ENGINE-LIFETIME byte totals (restored
-            # across resume by the snapshot) — assign, don't accumulate
-            bytes_up_total = report.bytes_up
-            bytes_down_total = report.bytes_down
-        elif engine is not None:
-            plan = scheduler.plan_round(r, n_units, sc.seed)
-            bx, by = scheduler.round_batch(plan, batch_size)
-            state, metrics, report = engine.run_round(
-                state, plan, {"tokens": jnp.asarray(bx),
-                              "labels": jnp.asarray(by)})
-            bytes_up_total += report.bytes_up
-            bytes_down_total += report.bytes_down
-        else:
-            chosen = sample_clients(rng, total_clients, clients_per_round)
-            bx, by = stack_client_batches([client_data[c] for c in chosen],
-                                          rng, batch_size)
-            with tel.span("train.round", round=r, method=method):
-                state, metrics = step_fn(state, {"tokens": jnp.asarray(bx),
-                                                 "labels": jnp.asarray(by)})
-            if tel.enabled:
-                # engine emits "round" events itself on the runtime path;
-                # the in-process path emits its own here (one per round)
-                ev = {"round": r, "method": method,
-                      "loss": float(metrics["loss"]),
-                      "wall_s": round(time.perf_counter() - t_round, 6)}
-                for k in ("jvp_abs_mean", "delta_norm"):
-                    if k in metrics:
-                        ev[k] = float(metrics[k])
-                if "fused_route" in metrics:
-                    ev["route"] = ("fused" if float(metrics["fused_route"])
-                                   else "standard")
-                tel.event("round", **ev)
-        if probe is not None and r == 0:
-            probe.sample("post_round_1")
-        if (r + 1) % eval_every == 0 or r == rounds - 1:
-            st = the_state(state)
-            accs = []
-            for i in range(0, min(len(x_te), 512), 64):
-                lg = eval_logits(st, jnp.asarray(x_te[i:i + 64]))
-                accs.append(np.asarray(
-                    accuracy_from_logits(lg, jnp.asarray(y_te[i:i + 64]))))
-            acc = float(np.mean(accs))
-            entry = {"round": r + 1, "acc": acc,
-                     "loss": float(metrics["loss"]),
-                     "t": time.time() - t0}
-            if "fused_route" in metrics:
-                entry["route"] = ("fused" if float(metrics["fused_route"])
-                                  else "standard")
-            extra = ""
+    profiling = (jax.profiler.trace(profile_dir) if profile_dir
+                 else contextlib.nullcontext())
+    with profiling:
+        for r in range(start_round, rounds):
+            t_round = time.perf_counter()
+            compiles_before = compile_stats()["compiles"] if tel.enabled else 0
             if engine is not None and async_mode:
-                entry["bytes_up"] = bytes_up_total
-                entry["bytes_down"] = bytes_down_total
-                extra = (f" up={bytes_up_total/1e6:.2f}MB "
-                         f"sim_t={report.sim_time_s:.0f}s "
-                         f"staleness={np.mean(report.staleness):.1f} "
-                         f"util={report.utilization:.2f}")
+                state, metrics, report = engine.run_version(state, batch_size)
+                # async reports carry ENGINE-LIFETIME byte totals (restored
+                # across resume by the snapshot) — assign, don't accumulate
+                bytes_up_total = report.bytes_up
+                bytes_down_total = report.bytes_down
             elif engine is not None:
-                entry["bytes_up"] = bytes_up_total
-                entry["bytes_down"] = bytes_down_total
-                extra = (f" up={bytes_up_total/1e6:.2f}MB "
-                         f"down={bytes_down_total/1e6:.2f}MB "
-                         f"survivors={report.n_validated}/"
-                         f"{report.cohort_size}")
-                if report.round_skipped:
-                    extra += " [below quorum: round skipped]"
-            history.append(entry)
-            if tel.enabled:
-                ev = {k: v for k, v in entry.items() if k != "t"}
-                ev["round"] = r   # 0-based, matching the "round" events
-                tel.event("eval", **ev)
-            log(f"[{method}] round {r+1:4d} loss={float(metrics['loss']):.4f} "
-                f"test_acc={acc:.4f} ({time.time()-t0:.0f}s){extra}")
-        maybe_checkpoint(r)
+                plan = scheduler.plan_round(r, n_units, sc.seed)
+                bx, by = scheduler.round_batch(plan, batch_size)
+                state, metrics, report = engine.run_round(
+                    state, plan, {"tokens": jnp.asarray(bx),
+                                  "labels": jnp.asarray(by)})
+                bytes_up_total += report.bytes_up
+                bytes_down_total += report.bytes_down
+            else:
+                with tel.span("train.prep", round=r):
+                    chosen = sample_clients(rng, total_clients,
+                                            clients_per_round)
+                    bx, by = stack_client_batches(
+                        [client_data[c] for c in chosen], rng, batch_size)
+                    batch = {"tokens": jnp.asarray(bx),
+                             "labels": jnp.asarray(by)}
+                with tel.span("train.dispatch", round=r):
+                    state, metrics = step_fn(state, batch)
+                if tel.enabled:
+                    # engine emits "round" events itself on the runtime path;
+                    # the in-process path emits its own here (one per round)
+                    with tel.span("train.loss_read", round=r):
+                        loss = float(metrics["loss"])
+                    ev = {"round": r, "method": method, "loss": loss,
+                          "wall_s": round(time.perf_counter() - t_round, 6)}
+                    compiles = compile_stats()["compiles"] - compiles_before
+                    if compiles:
+                        ev["compiles"] = compiles
+                    for k in ("jvp_abs_mean", "delta_norm"):
+                        if k in metrics:
+                            ev[k] = float(metrics[k])
+                    if "fused_route" in metrics:
+                        ev["route"] = ("fused" if float(metrics["fused_route"])
+                                       else "standard")
+                    tel.event("round", **ev)
+            if probe is not None and r == 0:
+                probe.sample("post_round_1")
+            if (r + 1) % eval_every == 0 or r == rounds - 1:
+                st = the_state(state)
+                accs = []
+                for i in range(0, min(len(x_te), 512), 64):
+                    lg = eval_logits(st, jnp.asarray(x_te[i:i + 64]))
+                    accs.append(np.asarray(
+                        accuracy_from_logits(lg, jnp.asarray(y_te[i:i + 64]))))
+                acc = float(np.mean(accs))
+                entry = {"round": r + 1, "acc": acc,
+                         "loss": float(metrics["loss"]),
+                         "t": time.time() - t0}
+                if "fused_route" in metrics:
+                    entry["route"] = ("fused" if float(metrics["fused_route"])
+                                      else "standard")
+                extra = ""
+                if engine is not None and async_mode:
+                    entry["bytes_up"] = bytes_up_total
+                    entry["bytes_down"] = bytes_down_total
+                    extra = (f" up={bytes_up_total/1e6:.2f}MB "
+                             f"sim_t={report.sim_time_s:.0f}s "
+                             f"staleness={np.mean(report.staleness):.1f} "
+                             f"util={report.utilization:.2f}")
+                elif engine is not None:
+                    entry["bytes_up"] = bytes_up_total
+                    entry["bytes_down"] = bytes_down_total
+                    extra = (f" up={bytes_up_total/1e6:.2f}MB "
+                             f"down={bytes_down_total/1e6:.2f}MB "
+                             f"survivors={report.n_validated}/"
+                             f"{report.cohort_size}")
+                    if report.round_skipped:
+                        extra += " [below quorum: round skipped]"
+                history.append(entry)
+                if tel.enabled:
+                    ev = {k: v for k, v in entry.items() if k != "t"}
+                    ev["round"] = r   # 0-based, matching the "round" events
+                    tel.event("eval", **ev)
+                log(f"[{method}] round {r+1:4d} "
+                    f"loss={float(metrics['loss']):.4f} "
+                    f"test_acc={acc:.4f} ({time.time()-t0:.0f}s){extra}")
+            maybe_checkpoint(r)
     history[-1]["personalized_acc"] = eval_personalized()
     if tel.enabled:
         probe.sample("end_of_run")
@@ -498,6 +521,10 @@ def main():
                          "of the run's spans to this path")
     ap.add_argument("--prom-out", default=None,
                     help="Prometheus textfile-collector snapshot path")
+    ap.add_argument("--profile-dir", default=None,
+                    help="record a profiler (xplane) trace of the round "
+                         "loop into this directory: the train.* spans on "
+                         "the host line beside the device operations")
     args = ap.parse_args()
     tel = make_telemetry(
         jsonl=None if args.telemetry in ("off", "none", "") else args.telemetry,
@@ -529,7 +556,8 @@ def main():
                         buffer_size=args.buffer_size,
                         staleness_decay=args.staleness_decay,
                         async_concurrency=args.async_concurrency,
-                        max_staleness=args.max_staleness)
+                        max_staleness=args.max_staleness,
+                        profile_dir=args.profile_dir)
     if tel.enabled:
         if args.trace_out:
             tel.export_chrome_trace(args.trace_out)

@@ -1,16 +1,18 @@
 """Unified telemetry: metrics registry, span tracing, sinks, memory
-probes, and the run-artifact report CLI (``python -m repro.obs.report``).
+probes, the compile counter, and the run-artifact report CLI
+(``python -m repro.obs.report``).
 
 Everything records host-side on already-returned values: telemetry-on is
 bit-identical to telemetry-off on every traced program; telemetry-off
 (``NULL``) is a preallocated no-op object. See ``obs/telemetry.py``.
+Importing this package starts the compile counter (``obs/compile.py``).
 """
+from repro.obs import compile as _compile
+from repro.obs.compile import compile_stats
 from repro.obs.memory import (
     MemoryProbe,
     device_memory_stats,
     live_array_bytes,
-    modeled_peak_bytes,
-    modeled_peak_of,
 )
 from repro.obs.metrics import (
     DEFAULT_BYTES_BUCKETS,
@@ -43,5 +45,7 @@ __all__ = [
     "SpanRecord", "Tracer", "chrome_trace_doc", "write_chrome_trace",
     "load_chrome_trace",
     "MemoryProbe", "live_array_bytes", "device_memory_stats",
-    "modeled_peak_bytes", "modeled_peak_of",
+    "compile_stats",
 ]
+
+_compile.register()

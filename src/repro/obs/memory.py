@@ -1,20 +1,18 @@
-"""Memory probes: modeled-vs-measured residency in ONE artifact.
+"""Memory probes: runtime residency sampled at probe points.
 
-``repro.analysis`` / ``launch.hlo_analysis.peak_live_bytes`` give the
-STATIC side — a buffer-liveness walk over compiled HLO bounding a
-program's peak live bytes before anything runs. This module adds the
-RUNTIME side — ``jax.live_arrays()`` totals and per-device
-``memory_stats()`` sampled at probe points — and pairs the two in a
-single ``memory`` telemetry event, so the real-TPU validation run
-(ROADMAP item 6) reads modeled and measured residency from the same
-JSONL row instead of reconciling two tools.
+``jax.live_arrays()`` totals and per-device ``memory_stats()``, recorded in
+a ``memory`` telemetry event. A device's peak is ``peak_bytes_in_use +
+peak_bytes_reserved``: on a TPU the compiled programs' temporaries are
+reserved apart and never reach ``peak_bytes_in_use``, so the in-use peak
+alone misses what sets a training step's peak (the benchmark's
+``peak_hbm_gib`` is the same sum).
 
 Probing is host-side and read-only: sampling allocates nothing on device
 and never touches a traced program.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict
 
 
 def live_array_bytes() -> int:
@@ -48,55 +46,32 @@ def device_memory_stats() -> Dict[str, Dict]:
     return out
 
 
-def modeled_peak_bytes(compiled_or_text) -> Optional[float]:
-    """Static peak-live-bytes of a compiled program (the PR 4 HLO
-    liveness analyzer). Accepts a ``jax.stages.Compiled`` or HLO text."""
-    from repro.launch.hlo_analysis import peak_live_bytes
-
-    text = (compiled_or_text if isinstance(compiled_or_text, str)
-            else compiled_or_text.as_text())
-    try:
-        return float(peak_live_bytes(text))
-    except Exception:
-        return None
-
-
-def modeled_peak_of(jit_fn, *args, **kwargs) -> Optional[float]:
-    """Lower+compile a jitted fn at the given avals and return its modeled
-    peak. jax caches the executable, so a subsequent call at the same
-    shapes reuses this compilation — probing costs no extra compile on
-    the hot path."""
-    try:
-        compiled = jit_fn.lower(*args, **kwargs).compile()
-    except Exception:
-        return None
-    return modeled_peak_bytes(compiled)
-
-
 class MemoryProbe:
     """Samples runtime residency into gauges + ``memory`` events.
 
-    ``sample(label)`` records live-array bytes (and device stats when
-    available); pass ``modeled_bytes`` to pair the static number with the
-    measurement in the same event — the modeled-vs-measured artifact.
+    ``sample(label)`` records live-array bytes and, where the backend keeps
+    statistics, each device's stats, the bytes in use over all devices and
+    each device's peak in use plus reserved (``device_peak_bytes``).
     """
 
     def __init__(self, telemetry):
         self.telemetry = telemetry
         self._g_live = telemetry.gauge("mem.live_array_bytes")
-        self._g_modeled = telemetry.gauge("mem.modeled_peak_bytes")
+        self._g_peak = telemetry.gauge("mem.device_peak_bytes")
 
-    def sample(self, label: str,
-               modeled_bytes: Optional[float] = None) -> Dict:
+    def sample(self, label: str) -> Dict:
         rec = {"label": label, "live_bytes": live_array_bytes()}
         stats = device_memory_stats()
         if stats:
             rec["device_stats"] = stats
             rec["device_bytes_in_use"] = sum(
                 s.get("bytes_in_use", 0) for s in stats.values())
-        if modeled_bytes is not None:
-            rec["modeled_peak_bytes"] = float(modeled_bytes)
-            self._g_modeled.set(float(modeled_bytes))
+            peaks = {dev: s["peak_bytes_in_use"]
+                     + s.get("peak_bytes_reserved", 0)
+                     for dev, s in stats.items() if "peak_bytes_in_use" in s}
+            if peaks:
+                rec["device_peak_bytes"] = peaks
+                self._g_peak.set(max(peaks.values()))
         self._g_live.set(rec["live_bytes"])
         self.telemetry.event("memory", **rec)
         return rec

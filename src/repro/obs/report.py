@@ -12,7 +12,7 @@ Detects what the run contained and renders the matching sections:
 * ``request`` events -> serving table (TTFT, latency, tok/s per request)
   plus aggregate percentiles and the adapter-cache hit rate from the
   final ``metrics`` snapshot
-* ``memory`` events  -> modeled-vs-measured residency lines
+* ``memory`` events  -> live and device residency lines
 """
 from __future__ import annotations
 
@@ -164,9 +164,10 @@ def memory_summary(events: List[Dict]) -> Optional[str]:
     if not mems:
         return None
     rows = [[e.get("label"), e.get("live_bytes"),
-             e.get("device_bytes_in_use"), e.get("modeled_peak_bytes")]
+             e.get("device_bytes_in_use"),
+             max((e.get("device_peak_bytes") or {}).values(), default=None)]
             for e in mems]
-    return _table(["probe", "live_bytes", "device_in_use", "modeled_peak"],
+    return _table(["probe", "live_bytes", "device_in_use", "device_peak"],
                   rows)
 
 

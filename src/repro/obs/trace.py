@@ -6,6 +6,12 @@ program. Nesting comes from a plain stack: spans opened inside an open
 span become its children in the exported view (Chrome trace renders
 containment on one track).
 
+While open, a span also holds a ``jax.profiler.TraceAnnotation`` of its
+name, so under a profiler trace (``jax.profiler.trace``, the train CLI's
+``--profile-dir``) it sits on the host line of the ``xplane`` trace, on
+the same clock as the device's operations. With no trace running the
+annotation records nothing.
+
 The export is the Trace Event Format's complete-event ("ph": "X") JSON,
 loadable in Perfetto / chrome://tracing: microsecond timestamps relative
 to tracer start, one pid per run, tid 0 for the main host thread.
@@ -19,6 +25,8 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 
 @dataclasses.dataclass
 class SpanRecord:
@@ -30,7 +38,7 @@ class SpanRecord:
 
 
 class _SpanCtx:
-    __slots__ = ("_tracer", "name", "args", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_depth", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict):
         self._tracer = tracer
@@ -38,6 +46,8 @@ class _SpanCtx:
         self.args = args
 
     def __enter__(self):
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._depth = len(self._tracer._stack)
         self._tracer._stack.append(self)
         self._t0 = time.perf_counter() - self._tracer._p0
@@ -50,6 +60,7 @@ class _SpanCtx:
             stack.pop()
         self._tracer.spans.append(SpanRecord(
             self.name, self._t0, t1 - self._t0, self._depth, self.args))
+        self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 

@@ -1,6 +1,8 @@
 """Unit tests for the repro.obs telemetry subsystem: metrics registry,
-span tracing + Chrome-trace export, sinks, the facade, and the report CLI.
-All host-side — nothing here touches jax beyond scalar coercion."""
+span tracing + Chrome-trace export, sinks, the facade, the report CLI, the
+spans' profiler annotations and the compile counter. All host-side: jax is
+touched only for scalar coercion, one CPU profiler trace and one tiny
+jit."""
 import json
 import math
 
@@ -280,12 +282,94 @@ def test_check_telemetry_jsonl_validator(tmp_path):
     assert check_telemetry_jsonl(str(empty))
 
 
-def test_memory_probe_emits_events():
-    from repro.obs import MemoryProbe
+def test_memory_probe_emits_events(monkeypatch, tmp_path):
+    from repro.obs import MemoryProbe, memory
+    stats = {"tpu:0": {"bytes_in_use": 10, "peak_bytes_in_use": 100,
+                       "peak_bytes_reserved": 23},
+             "tpu:1": {"bytes_in_use": 5, "peak_bytes_in_use": 50}}
+    monkeypatch.setattr(memory, "device_memory_stats", lambda: stats)
     sink = InMemorySink()
     tel = Telemetry(run_id="m", sinks=[sink])
-    MemoryProbe(tel).sample("here", modeled_bytes=123)
+    MemoryProbe(tel).sample("here")
     ev = sink.by_kind("memory")[0]
     assert ev["label"] == "here"
-    assert ev["modeled_peak_bytes"] == 123
+    # a device's peak is what it held in use plus what it reserved for
+    # compiled programs' temporaries
+    assert ev["device_peak_bytes"] == {"tpu:0": 123, "tpu:1": 50}
+    assert ev["device_bytes_in_use"] == 15
     assert ev["live_bytes"] >= 0
+    assert "modeled_peak_bytes" not in ev
+    assert tel.metrics_snapshot()["gauges"]["mem.device_peak_bytes"] == 123
+    path = tmp_path / "run.jsonl"
+    _write_jsonl(path, sink.events)
+    assert "device_peak" in render(str(path))
+
+
+# ---------------------------------------------------------------------------
+# profiler annotations + compile counter
+# ---------------------------------------------------------------------------
+
+class _FakeAnnotation:
+    opened: list = []
+
+    def __init__(self, name):
+        self.name = name
+        _FakeAnnotation.opened.append(name)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_enabled_span_opens_a_trace_annotation(monkeypatch):
+    from repro.obs import trace
+    monkeypatch.setattr(trace, "TraceAnnotation", _FakeAnnotation)
+    _FakeAnnotation.opened = []
+    tel = Telemetry(run_id="a", sinks=[InMemorySink()])
+    with tel.span("fl.round", round=3):
+        with tel.span("fl.wire"):
+            pass
+    # the same names, no span args folded into them
+    assert _FakeAnnotation.opened == ["fl.round", "fl.wire"]
+    assert [s.name for s in tel.tracer.spans] == ["fl.wire", "fl.round"]
+    # disabled telemetry opens none and allocates nothing
+    _FakeAnnotation.opened = []
+    with NULL.span("fl.round", round=3) as sp:
+        assert sp is _NULL_SPAN
+    assert _FakeAnnotation.opened == []
+
+
+def test_span_annotation_lands_in_a_profiler_trace(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    tel = Telemetry(run_id="p", sinks=[InMemorySink()])
+    with jax.profiler.trace(str(tmp_path)):
+        with tel.span("train.dispatch", round=0):
+            pass
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events}
+    assert "train.dispatch" in names
+
+
+def test_compile_stats_counts_one_compile_of_a_fresh_function():
+    import jax
+    from repro.obs import compile_stats
+    before = compile_stats()
+    x = np.arange(7, dtype=np.float32)
+    f = jax.jit(lambda v: v * 3.0 + before["compiles"])
+    f(x)
+    mid = compile_stats()
+    f(x)                                    # cached: no second compile
+    after = compile_stats()
+    assert mid["compiles"] - before["compiles"] == 1
+    assert after["compiles"] == mid["compiles"]
+    assert mid["backend_s"] > before["backend_s"]
+    assert mid["trace_s"] > before["trace_s"]
+    assert mid["seconds"] > before["seconds"]
+    assert mid["seconds"] <= (mid["trace_s"] + mid["lower_s"]
+                              + mid["backend_s"]) + 1e-9

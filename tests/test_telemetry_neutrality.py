@@ -149,3 +149,32 @@ def test_chrome_trace_exports_spans_from_real_run(tmp_path):
     doc = load_chrome_trace(str(path))
     names = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}
     assert "fl.round" in names
+
+
+def test_round_loop_spans_and_profile_trace(tmp_path):
+    """The in-process round loop spans each round's prep, dispatch and loss
+    read (no span claims to be the round: the dispatch returns before the
+    device finishes), counts the first round's compiles, and with a profile
+    directory puts the spans in an xplane trace beside the device ops."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from repro.launch.train import run_training
+    sink = InMemorySink()
+    tel = Telemetry(run_id="t", sinks=[sink])
+    losses = [h["loss"] for h in run_training(
+        arch=ARCH, task="toy", method="spry", rounds=2, clients_per_round=2,
+        total_clients=4, batch_size=2, eval_every=2, k_perturbations=2,
+        telemetry=tel, profile_dir=str(tmp_path), log=lambda *a: None)]
+    names = [s.name for s in tel.tracer.spans
+             if s.name.startswith("train.")]
+    assert names == ["train.prep", "train.dispatch", "train.loss_read"] * 2
+    rounds = sink.by_kind("round")
+    assert [ev["round"] for ev in rounds] == [0, 1]
+    assert rounds[0]["compiles"] >= 1 and "compiles" not in rounds[1]
+    assert rounds[1]["loss"] == losses[-1]
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    traced = {e.name for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for e in line.events}
+    assert {"train.prep", "train.dispatch", "train.loss_read"} <= traced
