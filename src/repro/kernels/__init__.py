@@ -10,10 +10,13 @@ dispatch.py    backend routing for the fused LoRA projection: models'
                ``proj`` differentiates through the Pallas kernel on TPU and
                the jnp reference mirror on CPU (REPRO_LORA_BACKEND override).
 swa_attention/ sliding-window flash attention (gemma3 / h2o-danube / zamba2)
-wkv6_scan/     RWKV6 data-dependent-decay recurrence, block-parallel over
-               (batch, heads)
-mamba2_scan/   Mamba2 state recurrence (zamba2 hybrid blocks), same
-               tangent-state-scratch design as wkv6_scan
+wkv6_scan/     RWKV6 data-dependent-decay recurrence, parallel over
+               (batch, heads) and chunk-parallel over the sequence: pairwise
+               decays inside sub-chunks, the state carried across them on
+               the MXU, two heads of 64 side by side on the lanes; tangents
+               by the product rule through the same primal pieces
+mamba2_scan/   Mamba2 state recurrence (zamba2 hybrid blocks): a per-token
+               state walk with a VMEM tangent-state scratch
 
 Every family also ships a ``*_mt_jvps`` contraction epilogue (lora / wkv6 /
 swa): when the estimator knows the site's output cotangent gy, the T

@@ -221,7 +221,7 @@ def _lora_tangent_fn(scale: float, has_xd: bool, interpret: bool):
 @functools.lru_cache(maxsize=None)
 def _wkv6_tangent_fn(has_ud: bool, interpret: bool):
     """Tangent-only WKV6 jvp, custom-vmapped onto ``wkv6_scan_mt_tangents``
-    (one primal state walk for all K tangents)."""
+    (one pass of the primal pieces for all K tangents)."""
     if has_ud:
         def base(r, k, v, w, u, rd, kd, vd, wd, ud):
             return wkv6_scan_mt_tangents(
@@ -460,7 +460,7 @@ def _wkv6_mix_jvp(primals, tangents):
     backend = get_backend()
     if backend in ("pallas", "interpret") and in_forward_ad_region():
         # primal (tangent-independent, so linearize still splits the rule):
-        # the compiled state-walk kernel on TPU — the jnp scan pays the
+        # the compiled chunked kernel on TPU — the jnp scan pays the
         # per-token HBM round-trip of the (hd,hd) state the kernel exists to
         # remove; under the interpreter keep the fast XLA scan (the kernel
         # dataflow is already exercised by the tangent route)
@@ -632,7 +632,7 @@ def lora_jvp_contract(gy, x, w, a, b, ad, bd, xd=None, *, scale=1.0):
 @functools.lru_cache(maxsize=None)
 def _wkv6_contract_fn(has_ud: bool, backend: str):
     """Single-tangent <gy, wkv6-ydot>, custom-vmapped onto
-    ``wkv6_scan_mt_jvps`` (per-token contraction inside the state walk) on
+    ``wkv6_scan_mt_jvps`` (contraction inside the chunked walk) on
     kernel backends; jnp mirror materializes-and-contracts (XLA fuses)."""
     if backend not in ("pallas", "interpret"):
         def jnp_base(gy, r, k, v, w, u, rd, kd, vd, wd, *maybe_ud):

@@ -1,15 +1,17 @@
-"""jit'd wrappers: (B,S,H,hd) <-> (B*H, S, hd) layout + padding of S.
+"""jit'd wrappers: (B,S,H,hd) -> the kernels' (B, S, H*hd) layout (a free
+reshape) + padding of S to whole grid blocks of whole sub-chunks
+(``kernel.CHUNK``).
 
 ``wkv6_scan``             single-pass primal
-``wkv6_scan_mt``          multi-tangent fused pass (y, ydots (T, ...)) — one
-                          walk of the primal state serves all T tangents
+``wkv6_scan_mt``          multi-tangent fused pass (y, ydots (T, ...)) — each
+                          sub-chunk's primal pieces serve all T tangents
 ``wkv6_scan_mt_tangents`` tangent-only variant (the AD dispatch route; its
                           primal output must come from the jnp mirror so
                           jax.linearize can split the custom-JVP rule)
 ``wkv6_scan_mt_jvps``     fused contraction epilogue: all T scalars
-                          <gy, ydot_t> — per-token ydots are contracted
-                          against gy inside the kernel and never written to
-                          HBM (the cotangent-known estimator route)
+                          <gy, ydot_t> — ydots are contracted against gy
+                          inside the kernel and never written to HBM (the
+                          cotangent-known estimator route)
 
 Tangent-axis contract: tangents carry a leading T axis — rds/kds/vds/wds are
 (T, B, S, H, hd) and uds (when the per-head bonus u carries a tangent) is
@@ -24,10 +26,29 @@ import jax.numpy as jnp
 
 from repro.kernels import sum_partials
 from repro.kernels.wkv6_scan.kernel import (
+    CHUNK,
     wkv6_scan_kernel,
     wkv6_scan_mt_jvps_kernel,
     wkv6_scan_mt_kernel,
 )
+
+
+def _block(block_s, S):
+    """Tokens per grid step: ``block_s``, or S if shorter, rounded up to
+    whole sub-chunks; S is padded to a multiple of it."""
+    return -(-min(block_s, S) // CHUNK) * CHUNK
+
+
+def _flat(x, pad, fill=0.0):
+    """(..., S, H, hd) -> (..., S + pad, H*hd) fp32: a free reshape to the
+    kernels' layout, padded along the sequence with ``fill``."""
+    x = x.astype(jnp.float32)
+    x = x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
+    if pad:
+        widths = [(0, 0)] * x.ndim
+        widths[-2] = (0, pad)
+        x = jnp.pad(x, widths, constant_values=fill)
+    return x
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
@@ -38,60 +59,31 @@ def wkv6_scan(r, k, v, w, u, block_s: int = 64, *, interpret: bool):
     state outside and uses the jnp reference for single steps.
     """
     B, S, H, hd = r.shape
-    bs = min(block_s, S)
+    bs = _block(block_s, S)
     pad = (-S) % bs
-
-    def to_bh(t):
-        t = t.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(B * H, S, hd)
-        if pad:
-            t = jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
-        return t
-
     # pad w with ones (decay) so padded steps keep state intact — irrelevant
-    # anyway since padded y rows are dropped
-    rb, kb, vb = to_bh(r), to_bh(k), to_bh(v)
-    wb = to_bh(w)
-    if pad:
-        wb = wb.at[:, S:, :].set(1.0)
-    ub = jnp.broadcast_to(u.astype(jnp.float32)[None], (B, H, hd)).reshape(B * H, hd)
-    y = wkv6_scan_kernel(rb, kb, vb, wb, ub, block_s=bs, interpret=interpret)
-    y = y[:, :S].reshape(B, H, S, hd).transpose(0, 2, 1, 3)
-    return y
+    # anyway since padded y rows are dropped (padding only ever trails)
+    y = wkv6_scan_kernel(_flat(r, pad), _flat(k, pad), _flat(v, pad),
+                         _flat(w, pad, 1.0), u.astype(jnp.float32).reshape(
+                             1, H * hd), hd=hd, block_s=bs,
+                         interpret=interpret)
+    return y[:, :S].reshape(B, S, H, hd)
 
 
 def _mt_layout(r, k, v, w, u, rds, kds, vds, wds, uds, block_s):
-    """Shared (B,S,H,hd)->(BH,S,hd) flattening + S padding for the mt entry
-    points. Padded steps keep both the primal state (w=1, kv=0) and every
-    tangent state (wd=0, kvd=0) intact; padded y/ydot rows are dropped."""
+    """Shared flattening + S padding for the mt entry points. Padded steps
+    keep both the primal state (w=1, kv=0) and every tangent state (wd=0,
+    kvd=0) intact; padded y/ydot rows are dropped."""
     B, S, H, hd = r.shape
     T = rds.shape[0]
-    bs = min(block_s, S)
+    bs = _block(block_s, S)
     pad = (-S) % bs
-
-    def to_bh(t):
-        t = t.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(B * H, S, hd)
-        if pad:
-            t = jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
-        return t
-
-    def to_bh_t(t):
-        t = t.astype(jnp.float32).transpose(0, 1, 3, 2, 4).reshape(
-            T, B * H, S, hd)
-        if pad:
-            t = jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        return t
-
-    rb, kb, vb, wb = to_bh(r), to_bh(k), to_bh(v), to_bh(w)
-    if pad:
-        wb = wb.at[:, S:, :].set(1.0)
-    rdb, kdb, vdb, wdb = to_bh_t(rds), to_bh_t(kds), to_bh_t(vds), to_bh_t(wds)
-    ub = jnp.broadcast_to(u.astype(jnp.float32)[None],
-                          (B, H, hd)).reshape(B * H, hd)
-    udb = None
-    if uds is not None:
-        udb = jnp.broadcast_to(uds.astype(jnp.float32)[:, None],
-                               (T, B, H, hd)).reshape(T, B * H, hd)
-    return (rb, kb, vb, wb, ub, rdb, kdb, vdb, wdb, udb), (B, S, H, hd, T, bs)
+    ops = [_flat(t, pad) for t in (r, k, v)] + [_flat(w, pad, 1.0)]
+    ops.append(u.astype(jnp.float32).reshape(1, H * hd))
+    ops += [_flat(t, pad) for t in (rds, kds, vds, wds)]
+    ops.append(None if uds is None
+               else uds.astype(jnp.float32).reshape(T, 1, H * hd))
+    return ops, (B, S, H, hd, T, bs)
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
@@ -101,25 +93,23 @@ def wkv6_scan_mt(r, k, v, w, u, rds, kds, vds, wds, uds=None,
     (T,B,S,H,hd) (+ uds (T,H,hd) or None). Returns (y, ydots) fp32."""
     ops, (B, S, H, hd, T, bs) = _mt_layout(r, k, v, w, u, rds, kds, vds, wds,
                                            uds, block_s)
-    y, yds = wkv6_scan_mt_kernel(*[o for o in ops if o is not None],
-                                 block_s=bs, interpret=interpret)
-    y = y[:, :S].reshape(B, H, S, hd).transpose(0, 2, 1, 3)
-    yds = yds[:, :, :S].reshape(T, B, H, S, hd).transpose(0, 1, 3, 2, 4)
-    return y, yds
+    y, yds = wkv6_scan_mt_kernel(*ops, hd=hd, block_s=bs,
+                                 interpret=interpret)
+    return (y[:, :S].reshape(B, S, H, hd),
+            yds[:, :, :S].reshape(T, B, S, H, hd))
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def wkv6_scan_mt_tangents(r, k, v, w, u, rds, kds, vds, wds, uds=None,
                           block_s: int = 64, *, interpret: bool):
     """Tangent-only fused pass -> ydots (T,B,S,H,hd). Same contract as
-    ``wkv6_scan_mt`` but skips the primal y output (the primal state walk
-    still runs in-kernel — the tangent recurrence needs S_{t-1})."""
+    ``wkv6_scan_mt`` but skips the primal y output (the primal pieces are
+    still computed in-kernel — the tangents need them and the state)."""
     ops, (B, S, H, hd, T, bs) = _mt_layout(r, k, v, w, u, rds, kds, vds, wds,
                                            uds, block_s)
-    yds = wkv6_scan_mt_kernel(*[o for o in ops if o is not None],
-                              block_s=bs, interpret=interpret,
+    yds = wkv6_scan_mt_kernel(*ops, hd=hd, block_s=bs, interpret=interpret,
                               emit_primal=False)
-    return yds[:, :, :S].reshape(T, B, H, S, hd).transpose(0, 1, 3, 2, 4)
+    return yds[:, :, :S].reshape(T, B, S, H, hd)
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
@@ -129,16 +119,11 @@ def wkv6_scan_mt_jvps(r, k, v, w, u, rds, kds, vds, wds, gy, uds=None,
 
     Same operand contract as ``wkv6_scan_mt`` plus the output cotangent
     gy: (B,S,H,hd); the T tangent outputs are contracted inside the kernel
-    and never reach HBM (only (BH, T) per-row partials do)."""
+    and never reach HBM (only (B, H/G, T) partials do)."""
     ops, (B, S, H, hd, T, bs) = _mt_layout(r, k, v, w, u, rds, kds, vds, wds,
                                            uds, block_s)
-    rb, kb, vb, wb, ub, rdb, kdb, vdb, wdb, udb = ops
+    *ops, udb = ops
     # zero-padded gy rows contribute exactly 0 to every partial
-    gyb = gy.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(B * H, S, hd)
-    pad = (-S) % bs
-    if pad:
-        gyb = jnp.pad(gyb, ((0, 0), (0, pad), (0, 0)))
-    parts = wkv6_scan_mt_jvps_kernel(rb, kb, vb, wb, ub, rdb, kdb, vdb, wdb,
-                                     gyb, udb, block_s=bs,
-                                     interpret=interpret)
+    parts = wkv6_scan_mt_jvps_kernel(*ops, _flat(gy, (-S) % bs), udb, hd=hd,
+                                     block_s=bs, interpret=interpret)
     return sum_partials(parts)

@@ -3,7 +3,8 @@
 Both are implemented as exact sequential recurrences with ``lax.scan`` over
 time — O(1) state, which is what makes the long_500k decode shape lower for
 these families. The TPU fast path for the RWKV6 recurrence is the
-kernels/wkv6_scan Pallas kernel (chunk-parallel inside VMEM); this module is
+kernels/wkv6_scan Pallas kernel (chunk-parallel: sub-chunks on the MXU, the
+state carried across them in VMEM); this module is
 the semantics-defining reference the kernel is tested against.
 
 RWKV6 (data-dependent decay, the paper's headline Finch feature):
@@ -159,8 +160,9 @@ def rwkv6_time_mix(cfg, p, x, peft_layer=None, lora_scale=1.0, state=None,
     if state is None and dispatch.use_kernel_mixers():
         # forward-gradient fast path (fresh state): the dispatched op lowers
         # K stacked tangents to the multi-tangent wkv6 Pallas kernel — one
-        # primal state walk for all K perturbations. The estimator's loss
-        # closures discard the carried state, so none is produced here.
+        # pass of the primal pieces for all K perturbations. The
+        # estimator's loss closures discard the carried state, so none is
+        # produced here.
         y = dispatch.wkv6_mix(r, k, v, w, u)
         state = None
     else:

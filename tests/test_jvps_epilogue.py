@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _wkv6_decay import wkv6_decay
 
 from repro.analysis import (
     assert_no_tangent_stack,
@@ -54,11 +55,11 @@ def _lora_problem(M=8, K=48, N=40, r=2, T=5, seed=0, scale=2.0):
     return (x, w, a, b), (xd, ad, bd), gy, scale
 
 
-def _wkv_problem(B=2, S=96, H=2, hd=16, T=3, seed=0):
+def _wkv_problem(B=2, S=96, H=2, hd=16, T=3, seed=0, decay="sigmoid"):
     ks = jax.random.split(jax.random.PRNGKey(seed), 11)
     r, k, v = (jax.random.normal(ks[i], (B, S, H, hd)) * 0.3
                for i in range(3))
-    w = jax.nn.sigmoid(jax.random.normal(ks[3], (B, S, H, hd)))
+    w = wkv6_decay(decay, ks[3], (B, S, H, hd))
     u = jax.random.normal(ks[4], (H, hd)) * 0.3
     rd, kd, vd = (jax.random.normal(ks[5 + i], (T, B, S, H, hd)) * 0.3
                   for i in range(3))
@@ -151,9 +152,22 @@ def test_lora_jvps_stacked_bitwise_equals_single_tangent_passes():
 # wkv6 epilogue kernel
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("with_ud,S", [(True, 96), (False, 96), (True, 75)])
-def test_wkv6_jvps_kernel_matches_oracle(with_ud, S):
-    (r, k, v, w, u), (rd, kd, vd, wd, ud), gy = _wkv_problem(S=S)
+@pytest.mark.parametrize("with_ud,S,decay,T", [
+    pytest.param(True, 96, "sigmoid", 3, id="True-96"),
+    pytest.param(False, 96, "sigmoid", 3, id="False-96"),
+    pytest.param(True, 75, "sigmoid", 3, id="True-75"),
+    pytest.param(True, 96, "strong", 3, id="strong-True-96"),
+    pytest.param(False, 75, "strong", 3, id="strong-False-75"),
+    pytest.param(True, 96, "near1", 3, id="near1-True-96"),
+    pytest.param(True, 10, "sigmoid", 3, id="True-10"),
+    pytest.param(False, 96, "sigmoid", 1, id="T1-False-96"),
+    pytest.param(True, 96, "sigmoid", 1, id="T1-True-96"),
+    pytest.param(False, 96, "sigmoid", 8, id="T8-False-96"),
+    pytest.param(True, 96, "sigmoid", 8, id="T8-True-96"),
+])
+def test_wkv6_jvps_kernel_matches_oracle(with_ud, S, decay, T):
+    (r, k, v, w, u), (rd, kd, vd, wd, ud), gy = _wkv_problem(S=S, T=T,
+                                                             decay=decay)
     uds = ud if with_ud else None
     jk = wkv6_scan_mt_jvps(r, k, v, w, u, rd, kd, vd, wd, gy, uds,
                            block_s=32, interpret=True)

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_shim import given, settings, st
+from _wkv6_decay import wkv6_decay
 
 from repro.kernels.lora_dual import lora_dual, lora_dual_ref
 from repro.kernels.swa_attention import swa_attention, swa_attention_ref
@@ -141,16 +142,25 @@ def test_swa_attention_window_sweep(wmul):
 # wkv6_scan
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("B,S,H,hd,bs", [(2, 128, 4, 32, 32),
-                                         (1, 100, 2, 64, 64),
-                                         (2, 64, 8, 16, 16),
-                                         (1, 256, 1, 8, 128)])
-def test_wkv6_scan_allclose(B, S, H, hd, bs):
+@pytest.mark.parametrize("B,S,H,hd,bs,decay", [
+    pytest.param(2, 128, 4, 32, 32, "sigmoid", id="2-128-4-32-32"),
+    pytest.param(1, 100, 2, 64, 64, "sigmoid", id="1-100-2-64-64"),
+    pytest.param(2, 64, 8, 16, 16, "sigmoid", id="2-64-8-16-16"),
+    pytest.param(1, 256, 1, 8, 128, "sigmoid", id="1-256-1-8-128"),
+    pytest.param(2, 96, 2, 16, 32, "strong", id="strong-2-96-2-16-32"),
+    pytest.param(1, 75, 2, 64, 64, "strong", id="strong-1-75-2-64-64"),
+    pytest.param(2, 96, 2, 16, 32, "near1", id="near1-2-96-2-16-32"),
+    pytest.param(2, 10, 2, 16, 64, "sigmoid", id="2-10-2-16-64"),
+])
+def test_wkv6_scan_allclose(B, S, H, hd, bs, decay):
+    """S under one sub-chunk (10), not a multiple of one (75, 100) and
+    across many (the state carry); w down to 1e-30 and exactly 0, and w at
+    1 - 1e-6."""
     ks = jax.random.split(jax.random.PRNGKey(5), 5)
     r = jax.random.normal(ks[0], (B, S, H, hd)) * 0.5
     k = jax.random.normal(ks[1], (B, S, H, hd)) * 0.5
     v = jax.random.normal(ks[2], (B, S, H, hd)) * 0.5
-    w = jax.nn.sigmoid(jax.random.normal(ks[3], (B, S, H, hd)))
+    w = wkv6_decay(decay, ks[3], (B, S, H, hd))
     u = jax.random.normal(ks[4], (H, hd)) * 0.5
     y = wkv6_scan(r, k, v, w, u, block_s=bs, interpret=True)
     yr, _ = wkv6_scan_ref(r, k, v, w, u)

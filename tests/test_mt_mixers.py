@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _wkv6_decay import wkv6_decay
 
 from repro.analysis import pallas_calls
 from repro.core.forward_grad import forward_gradient
@@ -30,10 +31,10 @@ from repro.kernels.wkv6_scan import (
 )
 
 
-def _wkv_problem(B=2, S=96, H=2, hd=16, T=3, seed=0):
+def _wkv_problem(B=2, S=96, H=2, hd=16, T=3, seed=0, decay="sigmoid"):
     ks = jax.random.split(jax.random.PRNGKey(seed), 10)
     r, k, v = (jax.random.normal(ks[i], (B, S, H, hd)) * 0.3 for i in range(3))
-    w = jax.nn.sigmoid(jax.random.normal(ks[3], (B, S, H, hd)))
+    w = wkv6_decay(decay, ks[3], (B, S, H, hd))
     u = jax.random.normal(ks[4], (H, hd)) * 0.3
     rd, kd, vd = (jax.random.normal(ks[5 + i], (T, B, S, H, hd)) * 0.3
                   for i in range(3))
@@ -57,9 +58,26 @@ def _swa_problem(B=1, H=4, KV=2, S=128, hd=32, T=3, seed=1):
 # wkv6 multi-tangent kernel
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("with_ud", [False, True])
-def test_wkv6_mt_matches_jvp_oracle(with_ud):
-    (r, k, v, w, u), (rd, kd, vd, wd, ud) = _wkv_problem()
+@pytest.mark.parametrize("with_ud,decay,S,T", [
+    pytest.param(False, "sigmoid", 96, 3, id="False"),
+    pytest.param(True, "sigmoid", 96, 3, id="True"),
+    pytest.param(False, "strong", 96, 3, id="strong"),
+    pytest.param(True, "strong", 96, 3, id="strong-ud"),
+    pytest.param(True, "strong", 75, 3, id="strong-ud-S75"),
+    pytest.param(False, "near1", 96, 3, id="near1"),
+    pytest.param(True, "near1", 96, 3, id="near1-ud"),
+    pytest.param(True, "sigmoid", 10, 3, id="S10-ud"),
+    pytest.param(False, "sigmoid", 96, 1, id="T1"),
+    pytest.param(True, "sigmoid", 96, 1, id="T1-ud"),
+    pytest.param(False, "sigmoid", 96, 8, id="T8"),
+    pytest.param(True, "sigmoid", 96, 8, id="T8-ud"),
+])
+def test_wkv6_mt_matches_jvp_oracle(with_ud, decay, S, T):
+    """Against T jax.jvp calls of the sequential oracle: w down to 1e-30 and
+    exactly 0, w at 1 - 1e-6, S under one sub-chunk, S = 96 across six
+    sub-chunks and three grid blocks (the state carry), T = 1 and 8."""
+    (r, k, v, w, u), (rd, kd, vd, wd, ud) = _wkv_problem(S=S, T=T,
+                                                         decay=decay)
     uds = ud if with_ud else None
     y, yds = wkv6_scan_mt(r, k, v, w, u, rd, kd, vd, wd, uds, block_s=32,
                           interpret=True)

@@ -74,9 +74,10 @@ def _lora(d=2048, n=2048, r=8):
 
 
 def _wkv6(h=32, hd=64):
-    """rwkv6-1.6b: 32 heads of 64."""
+    """rwkv6-1.6b: 32 heads of 64; ``seqt1``: one tangent, the K=1 route."""
     seq = ((B, S, h, hd), F32)
-    return dict(seq=seq, u=((h, hd), F32), seqt=((T, B, S, h, hd), F32))
+    return dict(seq=seq, u=((h, hd), F32), seqt=((T, B, S, h, hd), F32),
+                seqt1=((1, B, S, h, hd), F32))
 
 
 def _mamba2(h=64, hd=64, n=64):
@@ -123,6 +124,9 @@ def _cases():
         "wkv6_scan_mt_tangents": (
             wkv6_scan_mt_tangents,
             [wk["seq"]] * 4 + [wk["u"]] + [wk["seqt"]] * 4, {}),
+        "wkv6_scan_mt_tangents_t1": (
+            wkv6_scan_mt_tangents,
+            [wk["seq"]] * 4 + [wk["u"]] + [wk["seqt1"]] * 4, {}),
         "wkv6_scan_mt_jvps": (
             wkv6_scan_mt_jvps,
             [wk["seq"]] * 4 + [wk["u"]] + [wk["seqt"]] * 4 + [wk["seq"]],
